@@ -59,7 +59,7 @@ for _p in (str(_ROOT), str(_ROOT / "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from benchmarks.common import Bench
+from benchmarks.common import Bench, use_compile_cache
 from repro.core import zn540
 from repro.core.elements import (BLOCK, SUPERBLOCK, ElementSpec, hchunk,
                                  vchunk)
@@ -255,6 +255,7 @@ def main() -> None:
         # the grid's size
         args.random = len(grid_space(specs=specs, policies=policies))
 
+    use_compile_cache()
     flash, zone = zn540()
     if args.quick:
         specs = specs[:1]

@@ -106,7 +106,7 @@ def allocate(wear2d: np.ndarray,
     """Host-facing allocation entry point.
 
     ``impl``: 'xla' (jit fallback) or 'pallas' (TPU kernel via
-    :mod:`repro.kernels.zns_alloc.ops`, interpret-mode on CPU).
+    :mod:`repro.kernels.zns_alloc.ops`; compiled for the TPU).
     Returns (selection mask (n_groups, per_group), feasible).
     """
     if impl == "pallas":

@@ -601,13 +601,18 @@ def _take_lowest(cfg: EngineConfig, dyn: DynConfig, w2, a2, eligible,
     feasible = jnp.all(got_all | ~eligible)
     cols = cols.astype(jnp.int32)
     # whatever key selected the elements, the legacy ``_arrange`` ranks
-    # them by (wear, col) when assigning zone slots.  On the wear-aware
-    # path the top_k output is already in that order, so the reorder is
-    # an identity there (and lets ``by_wear`` stay traced).  Non-free
-    # filler (top_k rows with fewer than ``take`` free elements) must
-    # keep sorting last, or an in-use element could be reordered into
-    # the claimed take_eff prefix and stolen from its zone.
-    sel_free = jnp.take_along_axis(free, cols, axis=1)
+    # the claimed ones by (wear, col) when assigning zone slots.  On the
+    # wear-aware path the top_k output is already in that order, so the
+    # reorder is an identity there (and lets ``by_wear`` stay traced).
+    # Non-free filler (top_k rows with fewer than ``take`` free
+    # elements) must keep sorting last, or an in-use element could be
+    # reordered into the claimed take_eff prefix and stolen from its
+    # zone.  So must the selections past take_eff: first-fit claims the
+    # take_eff lowest *columns*, which a (wear, col) reorder of all
+    # ``take`` would swap for less-worn higher columns under a capacity
+    # override.
+    rank = jnp.arange(cfg.take, dtype=jnp.int32)[None, :]
+    sel_free = jnp.take_along_axis(free, cols, axis=1) & (rank < take_eff)
     sel_key = jnp.where(
         sel_free,
         jnp.take_along_axis(w2, cols, axis=1) * cfg.per_group + cols,

@@ -158,15 +158,19 @@ def simulate_fleet_ops(cols: jax.Array, pages: jax.Array,
        latencies (n_lanes, n_ops) f32, makespans (n_lanes,) f32).
     """
     P = cols.shape[-1]
-    t_page = jnp.broadcast_to(
-        jnp.asarray(t_page, jnp.float32), pages.shape)
+    # each op's service time, before the scan: an integer ceil (a float
+    # divide need not round alike on every backend) times the page
+    # cost.  Outside the scan no backend can fuse the multiply with the
+    # clock's add into one FMA, so every backend rounds both, as the
+    # model (and its numpy reference) does
+    dur = ((pages + P - 1) // P).astype(jnp.float32) * jnp.asarray(
+        t_page, jnp.float32)
 
-    def one_lane(cols_l, pages_l, ten_l, tp_l):
+    def one_lane(cols_l, pages_l, ten_l, dur_l):
         def step(carry, x):
             lun_free, ten_done = carry
-            c, pg, t, tp = x
+            c, pg, t, dur = x
             active = pg > 0
-            dur = (jnp.ceil(pg / P) * tp).astype(jnp.float32)
             # an op starts when its LUN columns free up AND its tenant
             # has completed its previous op (closed-loop issue)
             start = jnp.maximum(
@@ -183,10 +187,10 @@ def simulate_fleet_ops(cols: jax.Array, pages: jax.Array,
         init = (jnp.zeros(n_luns, jnp.float32),
                 jnp.zeros(n_tenants, jnp.float32))
         (lun_free, _), (done, lat) = jax.lax.scan(
-            step, init, (cols_l, pages_l, ten_l, tp_l))
+            step, init, (cols_l, pages_l, ten_l, dur_l))
         return done, lat, jnp.max(lun_free)
 
-    return jax.vmap(one_lane)(cols, pages, tenants, t_page)
+    return jax.vmap(one_lane)(cols, pages, tenants, dur)
 
 
 def run_fleet_trace(flash: FlashGeometry,

@@ -48,6 +48,7 @@ class FleetResult:
     erase_delta: np.ndarray  # (L, n_ops) block erasures
     pages: np.ndarray        # (L, n_ops) pages the op physically moved
                              #   (writes + FINISH padding + READ xfers)
+    cols: np.ndarray         # (L, n_ops, P) zone column -> LUN per op
     completions: np.ndarray  # (L, n_ops) op completion time (s)
     latencies: np.ndarray    # (L, n_ops) closed-loop op latency (s)
     makespans: np.ndarray    # (L,) lane makespan (s)
@@ -202,6 +203,7 @@ def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
                                     np.asarray(dyn.per_group))
 
     with sec("fleet.timing"):
+        cols = np.asarray(trace.cols)
         wp_b = np.asarray(trace.wp_before)
         wp_a = np.asarray(trace.wp_after)
         dummy = np.asarray(trace.dummy_delta)
@@ -219,19 +221,19 @@ def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
             np.float32(eng.flash.t_read + eng.flash.t_xfer),
             np.float32(eng.flash.t_prog + eng.flash.t_xfer))
         completions, latencies, makespans = timing.simulate_fleet_ops(
-            np.asarray(trace.cols), pages.astype(np.int32),
+            cols, pages.astype(np.int32),
             programs[:, :, TENANT_COL], t_page,
             eng.flash.n_luns, parity_tenant + 1)
         if profiler is not None:
             jax.block_until_ready(completions)
     with sec("fleet.decode"):
-        return _decode_fleet(programs, states, trace, dummy, pages,
+        return _decode_fleet(programs, states, trace, dummy, pages, cols,
                              completions, latencies, makespans,
                              n_tenants, parity_tenant, elem_mask,
                              telemetry, eng.cfg, dyn)
 
 
-def _decode_fleet(programs, states, trace, dummy, pages, completions,
+def _decode_fleet(programs, states, trace, dummy, pages, cols, completions,
                   latencies, makespans, n_tenants, parity_tenant,
                   elem_mask, telemetry, cfg=None, dyn=None) -> FleetResult:
     return FleetResult(
@@ -242,6 +244,7 @@ def _decode_fleet(programs, states, trace, dummy, pages, completions,
         dummy_delta=dummy,
         erase_delta=np.asarray(trace.erase_delta),
         pages=pages,
+        cols=cols,
         completions=np.asarray(completions),
         latencies=np.asarray(latencies),
         makespans=np.asarray(makespans),

@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the framework's compute hot-spots.
 
 Each kernel ships <name>.py (pl.pallas_call + BlockSpec VMEM tiling),
-ops.py (jit'd wrapper with impl switch: pallas on TPU / interpret on CPU /
-jnp fallbacks), and ref.py (pure-jnp oracle used by the allclose sweeps in
+ops.py (jit'd wrapper with impl switch: the pallas kernel, run in the
+Pallas interpreter only when the caller passes ``interpret=True``, or jnp
+fallbacks), and ref.py (pure-jnp oracle used by the allclose sweeps in
 tests/test_kernels.py).
 
   zns_alloc        wear-min per-LUN top-G selection (paper Table 4 hotspot)

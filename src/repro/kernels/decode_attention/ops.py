@@ -57,9 +57,8 @@ def decode_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def decode_attention(q, k, v, lengths, *, impl: str = "chunked",
-                     block_s: int = 512):
+                     block_s: int = 512, interpret: bool = False):
     if impl == "pallas":
-        interpret = jax.default_backend() != "tpu"
         return decode_attention_pallas(q, k, v, lengths, block_s=block_s,
                                        interpret=interpret)
     if impl == "chunked":

@@ -1,6 +1,7 @@
 """Public attention entry point with implementation switch.
 
-* ``pallas``  -- the TPU kernel (interpret-mode on CPU; used in tests).
+* ``pallas``  -- the TPU kernel (``interpret=True`` runs it in the Pallas
+  interpreter, as the CPU tests do).
 * ``chunked`` -- identical streaming-softmax math written as a
   ``lax.scan`` over kv blocks in plain jnp.  This is what the dry-run and
   the model stack use on CPU: it compiles on every XLA backend, keeps the
@@ -121,12 +122,12 @@ def attention_qchunk(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def attention(q, k, v, *, causal: bool = True, impl: str = "chunked",
-              block_q: int = 128, block_k: int = 128):
+              block_q: int = 128, block_k: int = 128,
+              interpret: bool = False):
     if impl == "qchunk":
         return attention_qchunk(q, k, v, causal=causal,
                                 block_q=max(block_q, 512))
     if impl == "pallas":
-        interpret = jax.default_backend() != "tpu"
         return flash_attention_pallas(q, k, v, causal=causal,
                                       block_q=block_q, block_k=block_k,
                                       interpret=interpret)

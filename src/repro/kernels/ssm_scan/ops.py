@@ -9,10 +9,10 @@ from repro.kernels.ssm_scan.ref import ssm_scan_ref
 from repro.kernels.ssm_scan.ssm_scan import ssm_scan_pallas
 
 
-def ssm_scan(x, dt, b, c, a, d, *, impl: str = "ref", chunk: int = 256):
+def ssm_scan(x, dt, b, c, a, d, *, impl: str = "ref", chunk: int = 256,
+             interpret: bool = False):
     """x: (BH, T, P); dt: (BH, T, P); b/c: (BH, T, N); a: (P, N); d: (P,)."""
     if impl == "pallas":
-        interpret = jax.default_backend() != "tpu"
         return ssm_scan_pallas(x, dt, b, c, a, d, chunk=chunk,
                                interpret=interpret)
     if impl == "ref":

@@ -1,8 +1,8 @@
 """Jit'd public entry point for the zns_alloc kernel.
 
-Selects the Pallas kernel on TPU, interpret-mode Pallas on CPU (used by
-tests and by ``ZNSDevice(alloc_impl='pallas')``), with the jnp reference
-always available via ``impl='ref'``.
+``impl='pallas'`` runs the Pallas kernel, compiled for the TPU unless
+the caller asks for ``interpret=True`` (the CPU tests do); the jnp
+reference is always available via ``impl='ref'``.
 """
 
 from __future__ import annotations
@@ -17,14 +17,12 @@ from repro.kernels.zns_alloc.zns_alloc import zns_alloc_pallas
 
 
 def _pick_group_block(n_groups: int) -> int:
-    for gb in (8, 4, 2, 1):
-        if n_groups % gb == 0:
-            return gb
-    return 1
+    # a TPU block's second-minor dim is a multiple of 8 or the whole axis
+    return 8 if n_groups % 8 == 0 else n_groups
 
 
 def zns_alloc(wear2d: jax.Array, avail2d: jax.Array, eligible: jax.Array,
-              *, take: int, impl: str = "pallas"
+              *, take: int, impl: str = "pallas", interpret: bool = False
               ) -> Tuple[jax.Array, jax.Array]:
     """Returns (sel bool mask (n_groups, per_group), feasible bool scalar).
 
@@ -33,7 +31,6 @@ def zns_alloc(wear2d: jax.Array, avail2d: jax.Array, eligible: jax.Array,
     if impl == "ref":
         sel, ok = zns_alloc_ref(wear2d, avail2d, eligible, take=take)
     else:
-        interpret = jax.default_backend() != "tpu"
         sel, ok = zns_alloc_pallas(
             wear2d, avail2d, eligible, take=take,
             group_block=_pick_group_block(wear2d.shape[0]),

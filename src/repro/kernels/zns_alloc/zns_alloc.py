@@ -41,11 +41,12 @@ BIG = 2**30  # python literal: safe to close over in the kernel
 def _kernel(wear_ref, avail_ref, elig_ref, sel_ref, ok_ref, *, take: int):
     wear = wear_ref[...]          # (GB, W) int32
     avail = avail_ref[...]        # (GB, W) int32
-    elig = elig_ref[...]          # (GB,) int32 (0/1)
+    elig = elig_ref[...]          # (GB, 1) int32 (0/1)
 
     allocatable = (avail == 0) | (avail == 3)
-    allocatable &= elig[:, None] != 0
-    ok_ref[...] = jnp.sum(allocatable.astype(jnp.int32), axis=1)
+    allocatable &= elig != 0
+    ok_ref[...] = jnp.sum(allocatable.astype(jnp.int32), axis=1,
+                          keepdims=True)
 
     keyed = jnp.where(allocatable, wear, BIG)
     gb, w = keyed.shape
@@ -59,13 +60,15 @@ def _kernel(wear_ref, avail_ref, elig_ref, sel_ref, ok_ref, *, take: int):
         min_idx = jnp.min(jnp.where(is_min, col, w), axis=1,
                           keepdims=True)                          # (GB, 1)
         pick = (col == min_idx) & (row_min < BIG)
-        sel = sel | pick
+        sel = jnp.where(pick, 1, sel)
         keyed = jnp.where(pick, BIG, keyed)                       # remove
         return keyed, sel
 
-    sel = jnp.zeros((gb, w), dtype=jnp.bool_)
+    # an int32 (not bool) selection carry: Mosaic cannot legalize a loop
+    # that carries an i1 vector
+    sel = jnp.zeros((gb, w), dtype=jnp.int32)
     _, sel = jax.lax.fori_loop(0, take, round_body, (keyed, sel))
-    sel_ref[...] = sel.astype(jnp.int32)
+    sel_ref[...] = sel
 
 
 @functools.partial(jax.jit,
@@ -74,7 +77,10 @@ def zns_alloc_pallas(wear2d: jax.Array, avail2d: jax.Array,
                      eligible: jax.Array, *, take: int,
                      group_block: int = 8,
                      interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Returns (sel int32 (n_groups, per_group), ok int32 (n_groups,))."""
+    """Returns (sel int32 (n_groups, per_group), ok int32 (n_groups,)).
+
+    The per-group operands travel as ``(n_groups, 1)`` columns: a TPU
+    block of a rank-1 array must span it whole or a multiple of 128."""
     n_groups, per_group = wear2d.shape
     gb = min(group_block, n_groups)
     if n_groups % gb:
@@ -88,17 +94,17 @@ def zns_alloc_pallas(wear2d: jax.Array, avail2d: jax.Array,
         in_specs=[
             pl.BlockSpec((gb, per_group), lambda g: (g, 0)),
             pl.BlockSpec((gb, per_group), lambda g: (g, 0)),
-            pl.BlockSpec((gb,), lambda g: (g,)),
+            pl.BlockSpec((gb, 1), lambda g: (g, 0)),
         ],
         out_specs=[
             pl.BlockSpec((gb, per_group), lambda g: (g, 0)),
-            pl.BlockSpec((gb,), lambda g: (g,)),
+            pl.BlockSpec((gb, 1), lambda g: (g, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_groups, per_group), jnp.int32),
-            jax.ShapeDtypeStruct((n_groups,), jnp.int32),
+            jax.ShapeDtypeStruct((n_groups, 1), jnp.int32),
         ],
         interpret=interpret,
     )(wear2d.astype(jnp.int32), avail2d.astype(jnp.int32),
-      eligible.astype(jnp.int32))
-    return sel, ok
+      eligible.astype(jnp.int32).reshape(n_groups, 1))
+    return sel, ok[:, 0]

@@ -14,17 +14,27 @@ Mesh semantics (DESIGN.md §5):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    # the sharding rules place parameters and leave the compiler to
+    # propagate activations; ``jax.make_mesh`` now defaults to Explicit
+    # axes, under which every ambiguous gather/scatter/contraction must
+    # state its output sharding instead
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for unit tests (requires >= data*model host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

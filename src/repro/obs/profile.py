@@ -18,9 +18,9 @@ Two independent instruments, both cheap enough to leave on:
   recompile leak the ROADMAP's interference regression turned out to
   be (see ``workloads.interference_sweep_engine``).
 
-Both degrade gracefully: if the monitoring hook or the private cache
-accessor disappears in a future jax, sections still report wall time
-and counters report ``-1`` rather than raising.
+Both read JAX APIs directly and raise if one goes missing: a counter
+that read ``-1`` on both sides of a window would show a recompile delta
+of 0 while measuring nothing.
 """
 
 from __future__ import annotations
@@ -62,12 +62,9 @@ class CompileLog:
 
     def install(self) -> "CompileLog":
         if not self.installed:
-            try:
-                jax.monitoring.register_event_duration_secs_listener(
-                    self._listen)
-                self.installed = True
-            except Exception:       # monitoring API moved: stay inert
-                pass
+            jax.monitoring.register_event_duration_secs_listener(
+                self._listen)
+            self.installed = True
         return self
 
     def snapshot(self) -> Dict[str, Dict]:
@@ -125,11 +122,8 @@ class Profiler:
 
 def jit_cache_size(fn) -> int:
     """Entries in a jitted function's compile cache (one per abstract
-    input signature seen), or -1 if the accessor is unavailable."""
-    try:
-        return int(fn._cache_size())
-    except Exception:
-        return -1
+    input signature seen)."""
+    return int(fn._cache_size())
 
 
 class RecompileCounter:

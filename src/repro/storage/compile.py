@@ -640,21 +640,24 @@ def workload_programs(eng: ZoneEngine, name: str, *, n_lanes: int = 2,
 
 
 def run_workload(eng: ZoneEngine, name: str, *, n_lanes: int = 2,
-                 seed: int = 0, pad_quantum: int = 64, obs=None,
-                 profiler=None, sanitize: bool = False
+                 seed: int = 0, dyns: Optional[Sequence[DynConfig]] = None,
+                 pad_quantum: int = 64, obs=None, profiler=None,
+                 sanitize: bool = False
                  ) -> Tuple[runner.FleetResult, Dict]:
     """Record workload ``name``, execute it as ONE class-tagged batched
     dispatch, and roll up per-tenant-class p99 predictability.
 
-    Returns ``(FleetResult, report)`` where ``report`` carries one
-    entry per traffic class (ops, pages, p50/p99/max latency,
-    ``p99_over_p50`` predictability) plus dispatch-level totals -- the
-    artifact ``fleet_search.py --workload`` writes and CI uploads.
-    Rows are pre-validated and (with ``sanitize=True``) the final
-    device states audited, as in :func:`replay_recorders`."""
+    ``dyns`` (one :class:`DynConfig` per lane) picks each lane's
+    element spec / ``alloc_policy``; default lanes run the engine's
+    primary config.  Returns ``(FleetResult, report)`` where ``report``
+    carries one entry per traffic class (ops, pages, p50/p99/max
+    latency, ``p99_over_p50`` predictability) plus dispatch-level
+    totals -- the artifact ``fleet_search.py --workload`` writes and CI
+    uploads.  Rows are pre-validated and (with ``sanitize=True``) the
+    final device states audited, as in :func:`replay_recorders`."""
     classes = WORKLOADS[name]
     recs = workload_programs(eng, name, n_lanes=n_lanes, seed=seed)
-    res = replay_recorders(eng, recs, n_tenants=len(classes),
+    res = replay_recorders(eng, recs, dyns=dyns, n_tenants=len(classes),
                            pad_quantum=pad_quantum, obs=obs,
                            profiler=profiler, sanitize=sanitize)
     report = {

@@ -2,7 +2,7 @@
 
 Three layers of equivalence, all required to be *exact*:
 
-1. random op sequences (hypothesis, `_hypothesis_stub` fallback) replayed
+1. random op sequences (hypothesis-generated) replayed
    through the legacy ``LegacyZNSDevice``, the engine-backed ``ZNSDevice``
    shim, and the raw ``run_program`` scan must leave identical
    wear/avail/pages/zone-map state, counters, and zone tables -- illegal
@@ -143,9 +143,7 @@ def test_differential_fuzz_programs(spec_i, max_active, rows):
     """Strategy-generated mixed valid/illegal programs: the legacy
     device, the engine-backed shim, and ONE ``run_program`` scan must
     leave exactly the same device state, and the scan's per-op ``ok``
-    flags must line up with where the legacy device raised.  (Degrades
-    to the seeded ``_hypothesis_stub`` enumeration when hypothesis is
-    not installed.)"""
+    flags must line up with where the legacy device raised."""
     spec = SPECS[spec_i]
     flash = tiny_flash()
     zone = ZoneGeometry(parallelism=4, n_segments=2)

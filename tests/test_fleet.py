@@ -140,6 +140,26 @@ def test_hetero_padding_matches_exact_geometry(spec):
                         f"padded {spec.name}")
 
 
+@pytest.mark.parametrize("spec", [SUPERBLOCK, BLOCK, vchunk(2)],
+                         ids=lambda s: s.name)
+def test_hetero_padding_first_fit_matches_exact_geometry(spec):
+    """First-fit under a capacity override claims the lowest free
+    *columns*, as the smaller device does -- not the least-worn of the
+    padded static take (regression: worn-out churn made the two pick
+    different elements)."""
+    small = E.ZoneEngine(tiny_flash(), ZoneGeometry(4, 2), spec,
+                         max_active=6, wear_aware=False)
+    big = tiny_engine(spec, n_segments=4)
+    prog = churn_program(n_zones=4, cycles=4)
+    s_exact, _ = small.run(small.init_state(), prog)
+    s_pad, _ = big.run(
+        big.init_state(), prog,
+        big.dyn(zone_pages=small.cfg.zone_pages, wear_aware=False,
+                n_zones=min(small.cfg.n_zones, big.cfg.n_zones)))
+    assert_states_equal(s_exact, s_pad, big.cfg.n_elements,
+                        f"padded first-fit {spec.name}")
+
+
 def test_hetero_batch_matches_independent_runs():
     """A mixed-geometry batched dispatch must leave every lane exactly
     as its independent (unbatched) run would."""

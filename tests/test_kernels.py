@@ -40,7 +40,8 @@ def test_zns_alloc_matches_ref(g, w, take):
     wear = jnp.asarray(rng.integers(0, 99, (g, w)), jnp.int32)
     avail = jnp.asarray(rng.choice([0, 1, 2, 3], (g, w)), jnp.int32)
     elig = jnp.asarray(rng.random(g) < 0.8)
-    s_pal, f_pal = zns_alloc(wear, avail, elig, take=take, impl="pallas")
+    s_pal, f_pal = zns_alloc(wear, avail, elig, take=take, impl="pallas",
+                             interpret=True)
     s_ref, ok = zns_alloc_ref(wear, avail, elig, take=take)
     assert (np.asarray(s_pal) == np.asarray(s_ref, bool)).all()
 
@@ -56,7 +57,8 @@ def test_zns_alloc_matches_exact_dp(seed):
     avail = rng.choice([0, 1, 2, 3], (g, w)).astype(np.int32)
     elig_idx = list(range(g))
     sel, feas = zns_alloc(jnp.asarray(wear), jnp.asarray(avail),
-                          jnp.ones(g, bool), take=take, impl="pallas")
+                          jnp.ones(g, bool), take=take, impl="pallas",
+                          interpret=True)
     dp = alloc_exact.solve(wear.reshape(-1), avail.reshape(-1),
                            np.repeat(np.arange(g), w), z=take * g,
                            k_max=take, l_min=g, eligible_groups=elig_idx)
@@ -100,7 +102,7 @@ def test_zns_alloc_matches_engine_claim(spec, seed):
     avail2d = avail[:n].reshape(cfg.n_groups, cfg.per_group)
     sel, feas = zns_alloc(jnp.asarray(wear2d), jnp.asarray(avail2d),
                           jnp.ones(cfg.n_groups, bool), take=cfg.take,
-                          impl="pallas")
+                          impl="pallas", interpret=True)
     assert bool(trace.ok[0]) == bool(feas)
     if bool(feas):
         g, c = np.nonzero(np.asarray(sel, bool))
@@ -128,7 +130,7 @@ def test_flash_attention_sweep(b, hq, hkv, s, d, causal, dtype):
     v = jnp.asarray(rng.standard_normal((b, hkv, s, d)), dtype)
     ref = attention_ref(q, k, v, causal=causal)
     out = attention(q, k, v, causal=causal, impl="pallas",
-                    block_q=32, block_k=32)
+                    block_q=32, block_k=32, interpret=True)
     assert rel_err(out, ref) < tol(dtype)
     out2 = attention(q, k, v, causal=causal, impl="chunked", block_k=32)
     assert rel_err(out2, ref) < tol(dtype)
@@ -139,7 +141,8 @@ def test_flash_attention_block_shape_invariance():
     q = jnp.asarray(rng.standard_normal((1, 2, 128, 32)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, 2, 128, 32)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, 2, 128, 32)), jnp.float32)
-    outs = [attention(q, k, v, impl="pallas", block_q=bq, block_k=bk)
+    outs = [attention(q, k, v, impl="pallas", block_q=bq, block_k=bk,
+                      interpret=True)
             for bq, bk in ((128, 128), (64, 32), (32, 64), (16, 16))]
     for o in outs[1:]:
         assert rel_err(o, outs[0]) < 1e-5
@@ -163,7 +166,8 @@ def test_decode_attention_sweep(b, hq, hkv, s, d, dtype):
     v = jnp.asarray(rng.standard_normal((b, s, hkv, d)), dtype)
     lengths = jnp.asarray(rng.integers(1, s + 1, b), jnp.int32)
     ref = decode_attention_ref(q, k, v, lengths)
-    out = decode_attention(q, k, v, lengths, impl="pallas", block_s=64)
+    out = decode_attention(q, k, v, lengths, impl="pallas", block_s=64,
+                           interpret=True)
     assert rel_err(out, ref) < tol(dtype)
     out2 = decode_attention(q, k, v, lengths, impl="chunked")
     assert rel_err(out2, ref) < tol(dtype)
@@ -177,10 +181,12 @@ def test_decode_attention_respects_length():
     k = jnp.asarray(rng.standard_normal((b, s, hkv, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, s, hkv, d)), jnp.float32)
     lengths = jnp.asarray([40], jnp.int32)
-    out1 = decode_attention(q, k, v, lengths, impl="pallas", block_s=32)
+    out1 = decode_attention(q, k, v, lengths, impl="pallas", block_s=32,
+                            interpret=True)
     k2 = k.at[:, 40:].set(999.0)
     v2 = v.at[:, 40:].set(-999.0)
-    out2 = decode_attention(q, k2, v2, lengths, impl="pallas", block_s=32)
+    out2 = decode_attention(q, k2, v2, lengths, impl="pallas", block_s=32,
+                            interpret=True)
     assert rel_err(out1, out2) < 1e-6
 
 
@@ -203,7 +209,8 @@ def test_ssm_scan_sweep(bh, t, p, n, chunk, dtype):
     a = jnp.asarray(-np.abs(rng.standard_normal((p, n))) - 0.1, jnp.float32)
     d = jnp.asarray(rng.standard_normal(p) * 0.1, jnp.float32)
     ref = ssm_scan_ref(x, dt, b, c, a, d)
-    out = ssm_scan(x, dt, b, c, a, d, impl="pallas", chunk=chunk)
+    out = ssm_scan(x, dt, b, c, a, d, impl="pallas", chunk=chunk,
+                   interpret=True)
     assert rel_err(out, ref) < tol(dtype)
 
 
@@ -232,7 +239,8 @@ def test_ssm_scan_chunk_invariance():
     c = jnp.asarray(rng.standard_normal((bh, t, n)) * 0.5, jnp.float32)
     a = jnp.asarray(-np.abs(rng.standard_normal((p, n))) - 0.1, jnp.float32)
     d = jnp.asarray(rng.standard_normal(p) * 0.1, jnp.float32)
-    outs = [ssm_scan(x, dt, b, c, a, d, impl="pallas", chunk=ch)
+    outs = [ssm_scan(x, dt, b, c, a, d, impl="pallas", chunk=ch,
+                     interpret=True)
             for ch in (8, 16, 32, 64)]
     for o in outs[1:]:
         assert rel_err(o, outs[0]) < 1e-6

@@ -2,8 +2,7 @@
 
 The ``alloc_policy="silent"`` axis commits a zone's block collection on
 the fly instead of pinning the whole static grid at ALLOC.  Three
-invariant families are fuzzed here (degrading to the seeded
-``_hypothesis_stub`` enumeration when hypothesis is not installed):
+invariant families are fuzzed here with hypothesis:
 
 1. every claim -- initial ALLOC and on-demand growth alike -- respects
    the wear-leveling bound (no claimed block more than ``wear_bound``

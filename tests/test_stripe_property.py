@@ -13,8 +13,6 @@ algebra for arbitrary (n_devices, chunk, page):
   all members, and no data slot ever lands on it;
 * striper agreement: the per-device WRITE page counts emitted by
   ``stripe_program`` match what ``locate_page`` predicts page by page.
-
-Runs under real hypothesis or the seeded ``_hypothesis_stub``.
 """
 
 import numpy as np

@@ -6,12 +6,11 @@ SUPERBLOCK + BLOCK + VCHUNK batch through one padded union
 lane to independent dispatches on engines built with each spec
 outright -- element wear/avail/pages, zone tables, counters, the lot.
 Programs are hypothesis-fuzzed (legal and illegal ops mixed, like
-``test_engine_diff.py``'s program fuzz; degrades to the seeded
-``_hypothesis_stub`` enumeration when hypothesis is missing), and the
-spec axis composes with the established capacity-shrink and allocator
-overrides.  The dyn-derived slot map that replaces the static
-per-spec ``element_pages`` reduction is property-checked against the
-closed forms for every element kind.
+``test_engine_diff.py``'s program fuzz), and the spec axis composes
+with the established capacity-shrink and allocator overrides.  The
+dyn-derived slot map that replaces the static per-spec
+``element_pages`` reduction is property-checked against the closed
+forms for every element kind.
 """
 
 import numpy as np

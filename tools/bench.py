@@ -78,6 +78,7 @@ for _p in (str(_ROOT), str(_ROOT / "src")):
 
 import numpy as np  # noqa: E402
 
+from benchmarks.common import use_compile_cache  # noqa: E402
 from repro.core import workloads  # noqa: E402
 from repro.fleet import grid_space  # noqa: E402
 from repro.fleet.search import fleet_vs_legacy_speedup  # noqa: E402
@@ -696,6 +697,7 @@ def main() -> int:
         ap.error("--skip-engine, --skip-fleet and --skip-paper together "
                  "leave nothing to benchmark")
 
+    use_compile_cache()
     rc = 0
     if not args.skip_engine:
         rc |= bench_engine(args)
