@@ -101,13 +101,15 @@ def emit_obs_artifacts(eng, configs, *, n_devices: int,
     from repro.obs import (ObsConfig, Profiler, RecompileCounter,
                            emit_fleet_obs)
 
-    programs, dyn, _ = build_fleet_batch(eng, configs,
-                                         n_devices=n_devices)
-    obs = ObsConfig(n_buckets=n_buckets, n_tenants=N_TENANTS + 1)
     prof = Profiler()
+    with prof.section("evaluator.build"):
+        programs, dyn, _ = build_fleet_batch(eng, configs,
+                                             n_devices=n_devices)
+    obs = ObsConfig(n_buckets=n_buckets, n_tenants=N_TENANTS + 1)
     res = run_fleet(eng, programs, dyn=dyn, n_tenants=N_TENANTS,
                     obs=obs, profiler=prof)
-    assert_all_ok(res)
+    with prof.section("fleet.check"):
+        assert_all_ok(res)
     labels = [f"{fc.describe()}/dev{d}"
               for fc in configs for d in range(n_devices)]
     return emit_fleet_obs(

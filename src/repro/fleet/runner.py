@@ -16,7 +16,6 @@ erase-block erasures, times are seconds.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from typing import Dict, List, Optional
 
@@ -28,6 +27,7 @@ from repro.core import timing
 from repro.core.elements import union_grid_mask
 from repro.core.engine import DeviceState, DynConfig, ZoneEngine
 from repro.fleet.tenants import TENANT_COL
+from repro.obs.profile import span
 
 
 @dataclasses.dataclass
@@ -83,10 +83,11 @@ class FleetResult:
         (derived from the dispatch's per-lane ``DynConfig``) excludes
         the never-allocated padding so wear statistics match a device
         built with the member spec outright."""
-        w = self.lane_wear(eng)[lanes]
-        if self.elem_mask is None:
-            return w.reshape(-1)
-        return w[self.elem_mask[lanes]]
+        with span("fleet.rollup"):
+            w = self.lane_wear(eng)[lanes]
+            if self.elem_mask is None:
+                return w.reshape(-1)
+            return w[self.elem_mask[lanes]]
 
     def tenant_pages(self, lanes: np.ndarray) -> Dict[int, int]:
         """Host pages per tenant summed over ``lanes`` (parity under
@@ -123,39 +124,40 @@ class FleetResult:
         and ``p99_over_p50`` -- the predictability ratio a
         well-isolated class keeps near 1.  ``names`` labels classes in
         tag order; unnamed tags keep their number."""
-        lanes = (np.arange(len(self.programs)) if lanes is None
-                 else np.asarray(lanes))
-        t = self.tenants[lanes].reshape(-1)
-        lat = self.latencies[lanes].reshape(-1)
-        pages = self.pages[lanes].reshape(-1)
-        host = self.host_delta[lanes].reshape(-1)
-        act = (self.programs[lanes][:, :, 0].reshape(-1) != zengine.OP_NOP
-               ) & self.ok[lanes].reshape(-1)
-        out: Dict[str, Dict[str, float]] = {}
-        for k in range(self.n_tenants):
-            name = (names[k] if names is not None and k < len(names)
-                    else str(k))
-            sel = act & (t == k)
-            if not sel.any():
-                out[name] = {"ops": 0.0, "pages": 0.0, "host_pages": 0.0,
-                             "mean_latency_s": 0.0, "p50_latency_s": 0.0,
-                             "p99_latency_s": 0.0, "max_latency_s": 0.0,
-                             "p99_over_p50": 0.0}
-                continue
-            l_k = lat[sel]
-            p50 = float(np.percentile(l_k, 50))
-            p99 = float(np.percentile(l_k, 99))
-            out[name] = {
-                "ops": float(sel.sum()),
-                "pages": float(pages[sel].sum()),
-                "host_pages": float(host[sel].sum()),
-                "mean_latency_s": float(l_k.mean()),
-                "p50_latency_s": p50,
-                "p99_latency_s": p99,
-                "max_latency_s": float(l_k.max()),
-                "p99_over_p50": p99 / p50 if p50 > 0 else 0.0,
-            }
-        return out
+        with span("fleet.rollup"):
+            lanes = (np.arange(len(self.programs)) if lanes is None
+                     else np.asarray(lanes))
+            t = self.tenants[lanes].reshape(-1)
+            lat = self.latencies[lanes].reshape(-1)
+            pages = self.pages[lanes].reshape(-1)
+            host = self.host_delta[lanes].reshape(-1)
+            act = (self.programs[lanes][:, :, 0].reshape(-1)
+                   != zengine.OP_NOP) & self.ok[lanes].reshape(-1)
+            out: Dict[str, Dict[str, float]] = {}
+            for k in range(self.n_tenants):
+                name = (names[k] if names is not None and k < len(names)
+                        else str(k))
+                sel = act & (t == k)
+                if not sel.any():
+                    out[name] = dict.fromkeys((
+                        "ops", "pages", "host_pages", "mean_latency_s",
+                        "p50_latency_s", "p99_latency_s", "max_latency_s",
+                        "p99_over_p50"), 0.0)
+                    continue
+                l_k = lat[sel]
+                p50 = float(np.percentile(l_k, 50))
+                p99 = float(np.percentile(l_k, 99))
+                out[name] = {
+                    "ops": float(sel.sum()),
+                    "pages": float(pages[sel].sum()),
+                    "host_pages": float(host[sel].sum()),
+                    "mean_latency_s": float(l_k.mean()),
+                    "p50_latency_s": p50,
+                    "p99_latency_s": p99,
+                    "max_latency_s": float(l_k.max()),
+                    "p99_over_p50": p99 / p50 if p50 > 0 else 0.0,
+                }
+            return out
 
 
 def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
@@ -174,9 +176,10 @@ def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
     ``obs`` (a ``repro.obs.ObsConfig``) threads the in-scan telemetry
     recorder through the dispatch; the result then carries per-lane
     histogram stacks in ``telemetry``.  ``profiler`` (a
-    ``repro.obs.Profiler``) splits the call into ``fleet.engine`` /
-    ``fleet.timing`` / ``fleet.decode`` sections (outputs are blocked
-    on inside each section so the wall times are honest).
+    ``repro.obs.Profiler``, else the current one) splits the call into
+    ``fleet.engine`` / ``fleet.timing`` / ``fleet.decode`` sections
+    (outputs are blocked on inside the first two when they are timed,
+    so their wall times hold the device work).
     """
     programs = np.asarray(programs, dtype=np.int32)
     if programs.ndim != 3 or programs.shape[-1] <= TENANT_COL:
@@ -184,25 +187,23 @@ def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
                          f"{programs.shape}")
     if parity_tenant is None:
         parity_tenant = n_tenants
-    sec = (profiler.section if profiler is not None
-           else (lambda _name: contextlib.nullcontext()))
-    with sec("fleet.engine"):
+    with span("fleet.engine", profiler) as timed:
         out = eng.run_batch(eng.init_state(), programs, dyn, obs=obs)
         states, trace = out[0], out[1]
         telemetry = out[2] if obs is not None else None
-        if profiler is not None:
+        elem_mask = None
+        if dyn is not None:
+            # each lane's real elements on the (possibly union-padded)
+            # static grid -- union lanes must exclude the padding cells
+            # from the wear rollups.  Read from the inputs while the
+            # device runs the lanes, timed or not.
+            elem_mask = union_grid_mask(
+                eng.cfg.n_elements, eng.cfg.per_group,
+                np.asarray(dyn.n_elements), np.asarray(dyn.per_group))
+        if timed is not None:
             jax.block_until_ready(states)
 
-    elem_mask = None
-    if dyn is not None:
-        # each lane's real elements on the (possibly union-padded)
-        # static grid -- union lanes must exclude the padding cells
-        # from the wear rollups
-        elem_mask = union_grid_mask(eng.cfg.n_elements, eng.cfg.per_group,
-                                    np.asarray(dyn.n_elements),
-                                    np.asarray(dyn.per_group))
-
-    with sec("fleet.timing"):
+    with span("fleet.timing", profiler) as timed:
         cols = np.asarray(trace.cols)
         wp_b = np.asarray(trace.wp_before)
         wp_a = np.asarray(trace.wp_after)
@@ -224,9 +225,9 @@ def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
             cols, pages.astype(np.int32),
             programs[:, :, TENANT_COL], t_page,
             eng.flash.n_luns, parity_tenant + 1)
-        if profiler is not None:
+        if timed is not None:
             jax.block_until_ready(completions)
-    with sec("fleet.decode"):
+    with span("fleet.decode", profiler):
         return _decode_fleet(programs, states, trace, dummy, pages, cols,
                              completions, latencies, makespans,
                              n_tenants, parity_tenant, elem_mask,
@@ -269,27 +270,29 @@ def config_report(res: FleetResult, eng: ZoneEngine,
     * ``makespan_s``: slowest member (the fleet completes a stripe only
       when every chunk is durable).
     """
-    lanes = np.asarray(lanes)
-    t = res.tenants[lanes]
-    host = int(res.host_delta[lanes][t != res.parity_tenant].sum())
-    par = int(res.host_delta[lanes][t == res.parity_tenant].sum())
-    dummy = int(res.dummy_delta[lanes].sum())
-    erases = int(res.erase_delta[lanes].sum())
-    wear = res.pooled_wear(eng, lanes)
-    mean_w = float(wear.mean()) if wear.size else 0.0
-    p99 = res.tenant_p99_latency(lanes)
-    return {
-        "host_pages": float(host),
-        "parity_pages": float(par),
-        "dummy_pages": float(dummy),
-        "dlwa": (host + par + dummy) / host if host else 1.0,
-        "block_erases": float(erases),
-        "max_wear": float(wear.max()) if wear.size else 0.0,
-        "wear_cv": float(wear.std() / mean_w) if mean_w > 0 else 0.0,
-        "p99_latency_s": max(p99.values()) if p99 else 0.0,
-        "makespan_s": float(res.makespans[lanes].max()),
-        "ops_ok": float(res.ok[lanes].sum()),
-    }
+    with span("fleet.rollup"):
+        lanes = np.asarray(lanes)
+        t = res.tenants[lanes]
+        host = int(res.host_delta[lanes][t != res.parity_tenant].sum())
+        par = int(res.host_delta[lanes][t == res.parity_tenant].sum())
+        dummy = int(res.dummy_delta[lanes].sum())
+        erases = int(res.erase_delta[lanes].sum())
+        wear = res.pooled_wear(eng, lanes)
+        mean_w = float(wear.mean()) if wear.size else 0.0
+        p99 = res.tenant_p99_latency(lanes)
+        return {
+            "host_pages": float(host),
+            "parity_pages": float(par),
+            "dummy_pages": float(dummy),
+            "dlwa": (host + par + dummy) / host if host else 1.0,
+            "block_erases": float(erases),
+            "max_wear": float(wear.max()) if wear.size else 0.0,
+            "wear_cv": (float(wear.std() / mean_w) if mean_w > 0
+                        else 0.0),
+            "p99_latency_s": max(p99.values()) if p99 else 0.0,
+            "makespan_s": float(res.makespans[lanes].max()),
+            "ops_ok": float(res.ok[lanes].sum()),
+        }
 
 
 def dispatch_cost(res: FleetResult) -> int:
@@ -317,11 +320,12 @@ def assert_all_ok(res: FleetResult, lanes: Optional[np.ndarray] = None
     is replayed through the :mod:`repro.check` verifier and the
     exception names the op kind, zone, and predicted error class with
     the shim's message -- not just the raw row."""
-    sel = slice(None) if lanes is None else lanes
-    real = res.programs[sel, :, 0] != zengine.OP_NOP
-    bad = real & ~res.ok[sel]
-    if not bad.any():
-        return
+    with span("fleet.check"):
+        sel = slice(None) if lanes is None else lanes
+        real = res.programs[sel, :, 0] != zengine.OP_NOP
+        bad = real & ~res.ok[sel]
+        if not bad.any():
+            return
     lane, idx = np.argwhere(bad)[0]
     row = res.programs[sel][lane, idx]
     msg = (f"illegal op at lane {lane} index {idx}: {row.tolist()}")

@@ -43,7 +43,6 @@ adaptive strategies minimize.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import itertools
 import math
@@ -60,6 +59,7 @@ from repro.core.geometry import FlashGeometry, ZoneGeometry
 from repro.fleet import runner
 from repro.fleet.tenants import (interleave_tenants, pad_programs,
                                  stripe_program, tag_tenant)
+from repro.obs.profile import span
 
 #: real tenants per mix (parity appends carry the tag N_TENANTS)
 N_TENANTS = 2
@@ -304,6 +304,10 @@ def build_fleet_batch(eng: ZoneEngine, configs: Sequence[FleetConfig],
     ``pad_quantum`` rounds the padded op axis up to a multiple (NOP
     rows are inert), so repeated same-size batches hit one compiled
     ``run_programs`` shape -- see :class:`Evaluator`.
+
+    Every config is validated before anything is built.  Under a
+    current profiler the op rows are timed as ``build.lanes`` and the
+    dyns as ``build.dyn``.
     """
     if not 0.0 < fidelity <= 1.0:
         raise ValueError(f"fidelity must be in (0, 1], got {fidelity}")
@@ -312,47 +316,54 @@ def build_fleet_batch(eng: ZoneEngine, configs: Sequence[FleetConfig],
                          "cannot take an effective-capacity override")
     seg_pages = eng.zone_geom.parallelism * eng.flash.pages_per_block
     nd_max = _nd_max(configs, n_devices)
-    lane_programs: List[np.ndarray] = []
-    dyns = []
-    merged_per_config: List[np.ndarray] = []
     for fc in configs:
         if fc.n_segments > eng.zone_geom.n_segments:
             raise ValueError(f"{fc}: n_segments exceeds the static "
                              f"geometry ({eng.zone_geom.n_segments})")
-        specs_mix = fc.specs_mix()
-        for s in specs_mix:
+        for s in fc.specs_mix():
             if s not in eng.members:
                 raise ValueError(
                     f"{fc}: spec {s.name} is not a member of the "
                     f"engine's config (members: "
                     f"{[m.name for m in eng.members]}); build the engine "
                     f"over the search space's spec set")
-        nd = fc.n_devices or n_devices
-        member_zp = seg_pages * fc.n_segments
-        n_data = nd - (1 if fc.parity else 0)
-        cap = n_data * member_zp
-        tenant_progs = MIXES[fc.mix](eng, cap)
-        merged = interleave_tenants(
-            [tag_tenant(p, t) for t, p in enumerate(tenant_progs)])
-        if fidelity < 1.0:
-            merged = merged[: max(1, math.ceil(fidelity * len(merged)))]
-        merged_per_config.append(merged)
-        lane_programs += stripe_program(
-            merged, n_devices=nd, chunk_pages=fc.chunk_pages,
-            parity=fc.parity, member_zone_pages=member_zp,
-            parity_tenant=N_TENANTS)
-        dyns += [eng.dyn(spec=specs_mix[d % len(specs_mix)],
-                         zone_pages=member_zp,
-                         wear_aware=fc.wear_aware,
-                         alloc_policy=fc.alloc_policy)
-                 for d in range(nd)]
-        # inert pad lanes square up a mixed-member-count batch
-        lane_programs += [np.zeros((0, 5), dtype=np.int32)] * (nd_max - nd)
-        dyns += [eng.dyn()] * (nd_max - nd)
-    q = max(1, pad_quantum)
-    n_ops = -(-max((len(p) for p in lane_programs), default=0) // q) * q
-    return (pad_programs(lane_programs, n_ops=n_ops), stack_dyn(dyns),
-            merged_per_config)
+    with span("build.lanes"):
+        lane_programs: List[np.ndarray] = []
+        merged_per_config: List[np.ndarray] = []
+        for fc in configs:
+            nd = fc.n_devices or n_devices
+            member_zp = seg_pages * fc.n_segments
+            n_data = nd - (1 if fc.parity else 0)
+            tenant_progs = MIXES[fc.mix](eng, n_data * member_zp)
+            merged = interleave_tenants(
+                [tag_tenant(p, t) for t, p in enumerate(tenant_progs)])
+            if fidelity < 1.0:
+                merged = merged[: max(1, math.ceil(fidelity * len(merged)))]
+            merged_per_config.append(merged)
+            lane_programs += stripe_program(
+                merged, n_devices=nd, chunk_pages=fc.chunk_pages,
+                parity=fc.parity, member_zone_pages=member_zp,
+                parity_tenant=N_TENANTS)
+            # inert pad lanes square up a mixed-member-count batch
+            lane_programs += ([np.zeros((0, 5), dtype=np.int32)]
+                              * (nd_max - nd))
+        q = max(1, pad_quantum)
+        n_ops = -(-max((len(p) for p in lane_programs), default=0)
+                  // q) * q
+        programs = pad_programs(lane_programs, n_ops=n_ops)
+    with span("build.dyn"):
+        dyns = []
+        for fc in configs:
+            nd = fc.n_devices or n_devices
+            specs_mix = fc.specs_mix()
+            dyns += [eng.dyn(spec=specs_mix[d % len(specs_mix)],
+                             zone_pages=seg_pages * fc.n_segments,
+                             wear_aware=fc.wear_aware,
+                             alloc_policy=fc.alloc_policy)
+                     for d in range(nd)]
+            dyns += [eng.dyn()] * (nd_max - nd)
+        dyn = stack_dyn(dyns)
+    return programs, dyn, merged_per_config
 
 
 class Evaluator:
@@ -382,8 +393,10 @@ class Evaluator:
     recompiling per batch.
 
     Observability (``repro.obs``): ``profiler`` threads per-section
-    counters (``evaluator.build`` / the ``fleet.*`` sections of
-    :func:`runner.run_fleet`) through every dispatch, and
+    counters through every dispatch: ``evaluator.build`` (split into
+    ``build.lanes``, the op rows, and ``build.dyn``, the lanes'
+    ``DynConfig``), the ``fleet.*`` sections of
+    :func:`runner.run_fleet`, ``fleet.check`` and ``fleet.rollup``; and
     ``recompiles`` watches the jit caches of the dispatch surface --
     :meth:`jit_cache` readings staying flat across repeated
     generations is the shape-stability property ``pad_quantum`` buys
@@ -428,9 +441,7 @@ class Evaluator:
         decisions adaptive strategies read off ``n_dispatches``)."""
         if not configs:
             return []
-        sec = (self.profiler.section if self.profiler is not None
-               else (lambda _name: contextlib.nullcontext()))
-        with sec("evaluator.build"):
+        with span("evaluator.build", self.profiler):
             programs, dyn, _ = build_fleet_batch(
                 self.eng, configs, n_devices=self.n_devices,
                 fidelity=fidelity, pad_quantum=self.pad_quantum)
@@ -438,7 +449,8 @@ class Evaluator:
                                n_tenants=N_TENANTS,
                                profiler=self.profiler)
         if self.check_legal:
-            runner.assert_all_ok(res)
+            with span("fleet.check", self.profiler):
+                runner.assert_all_ok(res)
         if self.sanitize:
             from repro.check import assert_states
             assert_states(self.eng.cfg, res.states, dyn,
@@ -447,27 +459,28 @@ class Evaluator:
         self.n_evals += fidelity * len(configs)
         self.lane_ops += runner.dispatch_cost(res)
         nd_max = _nd_max(configs, self.n_devices)
-        rows = []
-        for k, fc in enumerate(configs):
-            nd = fc.n_devices or self.n_devices
-            # pad lanes (all-NOP) of a narrower config are excluded:
-            # they would dilute the per-config rollup with empty lanes
-            lanes = np.arange(k * nd_max, k * nd_max + nd)
-            specs_mix = fc.specs_mix()
-            row: Dict = {
-                "config": fc.describe(),
-                "mix": fc.mix,
-                "n_segments": fc.n_segments,
-                "chunk_pages": fc.chunk_pages,
-                "parity": float(fc.parity),
-                "wear_aware": float(fc.wear_aware),
-                "spec": "+".join(s.name for s in specs_mix),
-                "n_devices": float(nd),
-                "alloc_policy": fc.alloc_policy,
-                "fidelity": float(fidelity),
-            }
-            row.update(runner.config_report(res, self.eng, lanes))
-            rows.append(row)
+        with span("fleet.rollup", self.profiler):
+            rows = []
+            for k, fc in enumerate(configs):
+                nd = fc.n_devices or self.n_devices
+                # pad lanes (all-NOP) of a narrower config are excluded:
+                # they would dilute the per-config rollup with empty lanes
+                lanes = np.arange(k * nd_max, k * nd_max + nd)
+                specs_mix = fc.specs_mix()
+                row: Dict = {
+                    "config": fc.describe(),
+                    "mix": fc.mix,
+                    "n_segments": fc.n_segments,
+                    "chunk_pages": fc.chunk_pages,
+                    "parity": float(fc.parity),
+                    "wear_aware": float(fc.wear_aware),
+                    "spec": "+".join(s.name for s in specs_mix),
+                    "n_devices": float(nd),
+                    "alloc_policy": fc.alloc_policy,
+                    "fidelity": float(fidelity),
+                }
+                row.update(runner.config_report(res, self.eng, lanes))
+                rows.append(row)
         return rows
 
     def objective(self, row: Dict) -> float:

@@ -18,8 +18,14 @@ model:
 * :mod:`repro.obs.profile`  -- dispatch-level profiling: wall time
   split into trace/lower/compile vs execute via the ``jax.monitoring``
   compile events, a recompile counter over the jit caches (keyed on
-  abstract input signatures), and per-section counters the fleet
-  runner / evaluator / evolve loop thread through;
+  abstract input signatures), and per-section counters.  Library code
+  marks its host work with ``span(name)``: a section of the profiler
+  it is given, else of the *current* one (the profiler whose section
+  is open), else nothing.  A span whose name is already open on that
+  profiler is not entered again, so rollups that call each other are
+  counted once.  The fleet runner, the evaluator and the replay take a
+  ``profiler=``; a check or rollup called inside any section finds the
+  current one;
 * :mod:`repro.obs.export`   -- Chrome/Perfetto ``trace_event`` JSON
   export (tenants -> tracks, ops -> duration events on the
   ``timing.simulate_fleet_ops`` clock) plus a counters/gauges metrics
@@ -39,8 +45,7 @@ from repro.obs.export import (MetricsRegistry, emit_fleet_obs,
                               fleet_trace_events, load_trace_schema,
                               validate_trace, write_trace)
 from repro.obs.profile import (COMPILE_LOG, CompileLog, Profiler,
-                               RecompileCounter, jit_cache_size,
-                               profile_dispatch)
+                               RecompileCounter, jit_cache_size, span)
 from repro.obs.recorder import (ObsConfig, TelemetryState,
                                 device_rollup, fleet_timelines,
                                 lane_timeline, telemetry_init,
@@ -52,7 +57,7 @@ __all__ = [
     "lane_timeline", "fleet_timelines", "tenant_timelines",
     "zone_timelines", "device_rollup",
     "COMPILE_LOG", "CompileLog", "Profiler", "RecompileCounter",
-    "jit_cache_size", "profile_dispatch",
+    "jit_cache_size", "span",
     "MetricsRegistry", "fleet_trace_events", "write_trace",
     "validate_trace", "load_trace_schema", "emit_fleet_obs",
 ]

@@ -36,6 +36,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.obs.profile import span
+
 #: opcode names (index = repro.core.engine opcode)
 OP_NAMES = ("NOP", "ALLOC", "WRITE", "FINISH", "RESET", "READ")
 
@@ -254,6 +256,8 @@ def emit_fleet_obs(res, eng, *, obs, out_prefix,
     validate_trace(write_trace(trace_path, events, meta=meta))
 
     lanes = recorder.fleet_timelines(obs, res.telemetry)
+    with span("fleet.rollup", profiler):
+        metrics = fleet_metrics(res, eng).as_dict()
     obs_obj = {
         "schema_version": 1,
         "meta": dict(meta or {}),
@@ -261,7 +265,7 @@ def emit_fleet_obs(res, eng, *, obs, out_prefix,
         "parity_tenant": int(res.parity_tenant),
         "lane_labels": (list(lane_labels) if lane_labels is not None
                         else [f"lane {i}" for i in range(len(lanes))]),
-        "metrics": fleet_metrics(res, eng).as_dict(),
+        "metrics": metrics,
         "timelines": {
             "lanes": lanes,
             "tenants": recorder.tenant_timelines(obs, res.telemetry),

@@ -1,6 +1,7 @@
-"""Dispatch-level profiling: compile-phase split + recompile counting.
+"""Dispatch-level profiling: sections, spans, compile-phase split and
+recompile counting.
 
-Two independent instruments, both cheap enough to leave on:
+Three instruments, all cheap enough to leave on:
 
 * :class:`CompileLog` -- a process-global accumulator of the
   ``jax.monitoring`` compile-phase duration events
@@ -17,18 +18,27 @@ Two independent instruments, both cheap enough to leave on:
   ``Evaluator.pad_quantum`` exists to buy); a growing count is the
   recompile leak the ROADMAP's interference regression turned out to
   be (see ``workloads.interference_sweep_engine``).
+* :func:`span` -- the one call library code uses to mark its host
+  work.  A :class:`Profiler` section makes its profiler the *current*
+  one for the section's duration; ``span(name)`` opens a section of
+  the profiler it is given, else of the current one, else does nothing
+  (a shared no-op context: no clock read, no allocation).  So a
+  rollup deep in the fleet code is timed whenever its caller runs
+  under a profiler, without a ``profiler=`` parameter on every
+  function in between.
 
-Both read JAX APIs directly and raise if one goes missing: a counter
-that read ``-1`` on both sides of a window would show a recompile delta
-of 0 while measuring nothing.
+The two counters read JAX APIs directly and raise if one goes missing:
+a counter that read ``-1`` on both sides of a window would show a
+recompile delta of 0 while measuring nothing.
 """
 
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import copy
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Set
 
 import jax
 
@@ -85,20 +95,36 @@ class Profiler:
     triggered), and ``execute_s`` (wall minus compile phases -- device
     execution plus host-side work).  Sections nest; compile time then
     shows up in every enclosing section, which is the truthful reading
-    (it *did* elapse there)."""
+    (it *did* elapse there).
+
+    While a section is open its profiler is the current one, which is
+    what :func:`span` falls back to; the previous current profiler is
+    restored on exit, exception or not.  :func:`span` always enters
+    through :meth:`section`, so a subclass that overrides it (to add a
+    trace annotation, say) sees every span.  A span whose name is
+    already open on the profiler is not entered again: rollups that
+    call each other are counted once.  Calling :meth:`section`
+    directly always counts."""
 
     def __init__(self, compile_log: Optional[CompileLog] = None) -> None:
         self.sections: Dict[str, Dict[str, float]] = {}
         self._log = compile_log if compile_log is not None else COMPILE_LOG
+        self._open: Set[str] = set()
 
     @contextlib.contextmanager
     def section(self, name: str):
+        token = _CURRENT.set(self)
+        fresh = name not in self._open
+        self._open.add(name)
         before = self._log.snapshot()
         t0 = time.perf_counter()
         try:
             yield self
         finally:
             wall = time.perf_counter() - t0
+            if fresh:
+                self._open.discard(name)
+            _CURRENT.reset(token)
             after = self._log.snapshot()
             d = self.sections.setdefault(name, {
                 "calls": 0.0, "wall_s": 0.0, "trace_s": 0.0,
@@ -118,6 +144,26 @@ class Profiler:
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         """JSON-ready copy of all section counters."""
         return copy.deepcopy(self.sections)
+
+
+#: the profiler whose section is open in this context, if any
+_CURRENT: contextvars.ContextVar[Optional[Profiler]] = \
+    contextvars.ContextVar("repro_obs_current_profiler", default=None)
+_NOOP = contextlib.nullcontext()
+
+
+def span(name: str, profiler: Optional[Profiler] = None):
+    """A section called ``name`` of ``profiler``, else of the current
+    profiler, else a shared no-op context.  Nothing is entered when
+    that profiler already has a section of this name open.
+    ``with span(...) as timed`` binds the profiler, or None where
+    nothing is timed.  A span does not wait for the device: code that
+    wants device work inside a span's wall time blocks itself, and
+    only when ``timed`` is set."""
+    prof = profiler if profiler is not None else _CURRENT.get()
+    if prof is None or name in prof._open:
+        return _NOOP
+    return prof.section(name)
 
 
 def jit_cache_size(fn) -> int:
@@ -155,18 +201,3 @@ class RecompileCounter:
     def delta(self, before: Dict[str, int]) -> Dict[str, int]:
         return {n: c - before.get(n, 0)
                 for n, c in self.counts().items()}
-
-
-def profile_dispatch(fn: Callable, *args,
-                     profiler: Optional[Profiler] = None,
-                     name: Optional[str] = None, **kwargs):
-    """Call ``fn`` under a profiler section, blocking on its outputs so
-    the section's wall time covers device execution.  Returns
-    ``(result, section counters)``; pass ``profiler`` to accumulate
-    into an existing one."""
-    prof = profiler if profiler is not None else Profiler()
-    label = name or getattr(fn, "__name__", "dispatch")
-    with prof.section(label):
-        out = fn(*args, **kwargs)
-        jax.block_until_ready(out)
-    return out, prof.sections[label]

@@ -53,6 +53,7 @@ from repro.core.engine import DynConfig, ZoneEngine, stack_dyn
 from repro.core.geometry import FlashGeometry
 from repro.fleet import runner
 from repro.fleet.tenants import TENANT_COL, pad_programs
+from repro.obs.profile import span
 from repro.storage.flashcache import CacheConfig, FlashCache
 from repro.storage.lsm import KVBenchConfig, LSMSimulator
 from repro.storage.traffic import burst_arrivals, zipfian_keys
@@ -329,7 +330,9 @@ def replay_recorders(eng: ZoneEngine,
     repeated same-shape replays hit one compiled ``run_programs``
     entry; ``obs`` / ``profiler`` thread ``repro.obs`` telemetry and
     section timers through, exactly as in
-    :func:`repro.fleet.runner.run_fleet`.  ``check`` asserts every real
+    :func:`repro.fleet.runner.run_fleet`, adding ``replay.prepare``
+    (the rows read, validated and padded, the dyns stacked) and
+    ``fleet.check``.  ``check`` asserts every real
     replayed op was legal -- a recorder/engine divergence fails loudly.
     ``sanitize`` additionally audits every lane's final device state
     with the :mod:`repro.check` sanitizer (host-side numpy; no extra
@@ -341,26 +344,28 @@ def replay_recorders(eng: ZoneEngine,
     would not fail, they alias (op/zone clipping) or walk pointers
     backwards -- scan-time garbage with no error at all.
     """
-    programs = [np.asarray(r.program(), dtype=np.int32)
-                for r in recorders]
-    for k, p in enumerate(programs):
-        validate_rows(p, n_tenants=n_tenants,
-                      parity_tenant=parity_tenant,
-                      where=f"recorder {k} program")
-    q = max(1, pad_quantum)
-    n_ops = -(-max((len(p) for p in programs), default=1) // q) * q
-    batch = pad_programs(programs, n_ops=max(n_ops, q))
-    dyn = None
-    if dyns is not None:
-        if len(dyns) != len(recorders):
-            raise ValueError(f"{len(dyns)} dyns for {len(recorders)} "
-                             f"recorders")
-        dyn = stack_dyn(list(dyns))
+    with span("replay.prepare", profiler):
+        programs = [np.asarray(r.program(), dtype=np.int32)
+                    for r in recorders]
+        for k, p in enumerate(programs):
+            validate_rows(p, n_tenants=n_tenants,
+                          parity_tenant=parity_tenant,
+                          where=f"recorder {k} program")
+        q = max(1, pad_quantum)
+        n_ops = -(-max((len(p) for p in programs), default=1) // q) * q
+        batch = pad_programs(programs, n_ops=max(n_ops, q))
+        dyn = None
+        if dyns is not None:
+            if len(dyns) != len(recorders):
+                raise ValueError(f"{len(dyns)} dyns for {len(recorders)} "
+                                 f"recorders")
+            dyn = stack_dyn(list(dyns))
     res = runner.run_fleet(eng, batch, dyn=dyn, n_tenants=n_tenants,
                            parity_tenant=parity_tenant, obs=obs,
                            profiler=profiler)
     if check:
-        runner.assert_all_ok(res)
+        with span("fleet.check", profiler):
+            runner.assert_all_ok(res)
     if sanitize:
         assert_states(eng.cfg, res.states, dyn, where="replay states")
     return res
@@ -376,7 +381,8 @@ def lane_state(res: runner.FleetResult, lane: int):
 def lane_metrics(eng: ZoneEngine, res: runner.FleetResult,
                  lane: int) -> Dict[str, float]:
     """``eng.metrics`` of one replay lane (host/dummy/DLWA/erases)."""
-    return eng.metrics(lane_state(res, lane))
+    with span("fleet.rollup"):
+        return eng.metrics(lane_state(res, lane))
 
 
 # --------------------------------------------------------------------- #

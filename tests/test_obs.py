@@ -16,9 +16,14 @@ state machine).  On top of that:
   (subset validator always; real ``jsonschema`` when installed);
 * the profiler / recompile counter read real jit caches: a new shape
   compiles, a repeat does not, and repeated same-shape ``Evaluator``
-  generations keep a flat cache (the ``pad_quantum`` guarantee).
+  generations keep a flat cache (the ``pad_quantum`` guarantee);
+* ``span`` finds the current profiler, counts a nested same-name span
+  once, reaches a ``section`` override, adds no device sync, and the
+  two-pass fleet builder returns the one-pass builder's arrays.
 """
 
+import contextlib
+import dataclasses
 import json
 import importlib.util
 import pathlib
@@ -34,7 +39,7 @@ from repro.core.elements import BLOCK, FIXED, SUPERBLOCK, hchunk, vchunk
 from repro.core.geometry import FlashGeometry, ZoneGeometry
 from repro.obs import (ObsConfig, Profiler, RecompileCounter,
                        device_rollup, fleet_timelines, jit_cache_size,
-                       lane_timeline, profile_dispatch, tenant_timelines,
+                       lane_timeline, span, tenant_timelines,
                        validate_trace, zone_timelines)
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -431,17 +436,6 @@ def test_profiler_sections_accumulate():
     assert prof.sections["a"]["calls"] == 2.0
 
 
-def test_profile_dispatch_blocks_and_counts():
-    eng = tiny_engine(SUPERBLOCK)
-    prog = _mixed_program(eng, n=8)
-    prof = Profiler()
-    (state, _trace), sec = profile_dispatch(
-        eng.run, eng.init_state(), prog, profiler=prof, name="run")
-    assert int(state.host_pages) >= 0
-    assert sec["calls"] == 1.0 and sec["wall_s"] > 0.0
-    assert prof.sections["run"] is sec
-
-
 def test_recompile_counter_sees_new_shapes():
     eng = tiny_engine(SUPERBLOCK)
     rc = RecompileCounter(run_program=E.run_program)
@@ -497,3 +491,244 @@ def test_evolve_history_carries_profile_when_instrumented():
     # instrumentation must not change what the search found
     assert [r["best_so_far"] for r in inst.history] == \
         [r["best_so_far"] for r in plain.history]
+
+
+# --------------------------------------------------------------------- #
+# spans: the current profiler, the same-name rule, the library's spans
+# --------------------------------------------------------------------- #
+def test_span_without_a_profiler_is_a_shared_noop():
+    idle = Profiler()
+    a, b = span("a"), span("b")
+    assert a is b                        # shared: nothing allocated
+    with a:
+        with span("c"):
+            pass
+    assert idle.sections == {}
+    assert span("a", None) is a
+
+
+@pytest.mark.parametrize("raises", [False, True])
+def test_section_makes_its_profiler_current_until_exit(raises):
+    outer, inner = Profiler(), Profiler()
+    with (pytest.raises(RuntimeError) if raises
+          else contextlib.nullcontext()):
+        with outer.section("call"):
+            with span("a"):
+                pass
+            with inner.section("call"):
+                with span("b"):
+                    pass
+            with span("c"):          # the outer one is current again
+                if raises:
+                    raise RuntimeError("inside a span")
+    assert set(outer.sections) == {"call", "a", "c"}
+    assert set(inner.sections) == {"call", "b"}
+    with span("after"):
+        pass
+    assert "after" not in outer.sections | inner.sections
+    with outer.section("again"):     # the same-name rule was reset too
+        with span("c"):
+            pass
+    assert outer.sections["c"]["calls"] == 2.0
+
+
+@pytest.mark.parametrize("explicit", [False, True])
+def test_nested_span_with_the_same_name_counts_once(explicit):
+    prof = Profiler()
+    with prof.section("call"):
+        with span("fleet.rollup", prof if explicit else None):
+            with span("fleet.rollup"):
+                with span("fleet.rollup", prof):
+                    pass
+        with span("fleet.rollup"):
+            pass
+    assert prof.sections["fleet.rollup"]["calls"] == 2.0
+    assert prof.sections["call"]["calls"] == 1.0
+
+
+def test_a_section_override_receives_every_span():
+    seen = []
+
+    class Annotating(Profiler):
+        @contextlib.contextmanager
+        def section(self, name):
+            seen.append(name)
+            with super().section(name) as prof:
+                yield prof
+
+    prof = Annotating()
+    with prof.section("call"):
+        with span("a"):
+            with span("a"):          # open already: not entered
+                pass
+        with span("b", prof):
+            pass
+    with span("c", prof):
+        with span("d"):              # current inside its own section
+            pass
+    assert seen == ["call", "a", "b", "c", "d"]
+
+
+def test_evaluator_spans_build_check_and_rollups():
+    from repro.fleet import Evaluator, grid_space
+    eng, _c, _r, _o = _tiny_fleet()
+    configs = grid_space(segments=(4,), chunks=(64,),
+                         parities=(False, True), wear=(True,))
+    prof = Profiler()
+    Evaluator(eng, n_devices=2, profiler=prof).evaluate(configs)
+    sec = prof.sections
+    assert {"evaluator.build", "build.lanes", "build.dyn",
+            "fleet.engine", "fleet.timing", "fleet.decode",
+            "fleet.check", "fleet.rollup"} <= set(sec)
+    # one check and one rollup span a dispatch, however many configs
+    assert sec["fleet.check"]["calls"] == sec["fleet.rollup"]["calls"] \
+        == 1.0
+    assert (sec["build.lanes"]["wall_s"] + sec["build.dyn"]["wall_s"]
+            <= sec["evaluator.build"]["wall_s"])
+
+
+def test_replay_and_lane_metrics_span_prepare_check_and_rollups():
+    from repro import storage as S
+    eng = tiny_engine(SUPERBLOCK)
+    rec = S.RecordingBackend(eng.flash, zone_pages=eng.cfg.zone_pages,
+                             n_zones=4, max_active=3)
+    S.record_cache(rec, n_accesses=60, n_keys=12, seed=0,
+                   capacity_zones=4, obj_pages=2)
+    prof = Profiler()
+    with prof.section("call"):
+        res = S.replay_recorders(eng, [rec, rec],
+                                 dyns=[eng.dyn(), eng.dyn()],
+                                 pad_quantum=32, profiler=prof)
+        for lane in range(2):
+            S.lane_metrics(eng, res, lane)
+            res.pooled_wear(eng, np.asarray([lane]))
+        res.tenant_class_report()
+    sec = prof.sections
+    assert {"replay.prepare", "fleet.check", "fleet.rollup",
+            "fleet.engine"} <= set(sec)
+    assert sec["fleet.check"]["calls"] == 1.0
+    assert sec["fleet.rollup"]["calls"] == 5.0
+    assert sec["replay.prepare"]["wall_s"] <= sec["call"]["wall_s"]
+
+
+@pytest.mark.parametrize("mode", ["none", "given", "current"])
+def test_only_the_timed_engine_and_timing_sections_block(monkeypatch,
+                                                         mode):
+    import jax
+    from repro.fleet import N_TENANTS, Evaluator, build_fleet_batch, \
+        grid_space, run_fleet
+    eng, configs, _r, _o = _tiny_fleet()
+    programs, dyn, _ = build_fleet_batch(eng, configs, n_devices=2)
+    run_fleet(eng, programs, dyn=dyn, n_tenants=N_TENANTS)   # compile
+    calls = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        calls.append(1)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    prof = Profiler() if mode != "none" else None
+    if mode == "current":
+        with prof.section("call"):
+            run_fleet(eng, programs, dyn=dyn, n_tenants=N_TENANTS)
+    else:
+        run_fleet(eng, programs, dyn=dyn, n_tenants=N_TENANTS,
+                  profiler=prof)
+    # a timed run blocks in fleet.engine and fleet.timing, nothing more
+    assert len(calls) == (0 if prof is None else 2)
+    # and the build, check and rollup spans of a whole evaluation add
+    # no block of their own
+    del calls[:]
+    ev = Evaluator(eng, n_devices=2, profiler=prof)
+    ev.evaluate(grid_space(segments=(4,), chunks=(64,),
+                           parities=(False, True), wear=(True,)))
+    assert len(calls) == (0 if prof is None else 2)
+    if prof is not None:
+        assert {"build.dyn", "fleet.check", "fleet.rollup"} <= set(
+            prof.sections)
+
+
+def _one_pass_build(eng, configs, *, n_devices, fidelity=1.0,
+                    pad_quantum=1):
+    """The fleet builder as one loop over configs, lanes and dyns
+    together: the arrays the two-pass builder has to return."""
+    import math
+    from repro.core.engine import stack_dyn
+    from repro.fleet import search
+    from repro.fleet.tenants import (interleave_tenants, pad_programs,
+                                     stripe_program, tag_tenant)
+    seg_pages = eng.zone_geom.parallelism * eng.flash.pages_per_block
+    nd_max = search._nd_max(configs, n_devices)
+    lane_programs, dyns, merged_per_config = [], [], []
+    for fc in configs:
+        specs_mix = fc.specs_mix()
+        nd = fc.n_devices or n_devices
+        member_zp = seg_pages * fc.n_segments
+        cap = (nd - (1 if fc.parity else 0)) * member_zp
+        merged = interleave_tenants(
+            [tag_tenant(p, t)
+             for t, p in enumerate(search.MIXES[fc.mix](eng, cap))])
+        if fidelity < 1.0:
+            merged = merged[: max(1, math.ceil(fidelity * len(merged)))]
+        merged_per_config.append(merged)
+        lane_programs += stripe_program(
+            merged, n_devices=nd, chunk_pages=fc.chunk_pages,
+            parity=fc.parity, member_zone_pages=member_zp,
+            parity_tenant=search.N_TENANTS)
+        dyns += [eng.dyn(spec=specs_mix[d % len(specs_mix)],
+                         zone_pages=member_zp, wear_aware=fc.wear_aware,
+                         alloc_policy=fc.alloc_policy)
+                 for d in range(nd)]
+        lane_programs += [np.zeros((0, 5), dtype=np.int32)] * (nd_max - nd)
+        dyns += [eng.dyn()] * (nd_max - nd)
+    q = max(1, pad_quantum)
+    n_ops = -(-max((len(p) for p in lane_programs), default=0) // q) * q
+    return (pad_programs(lane_programs, n_ops=n_ops), stack_dyn(dyns),
+            merged_per_config)
+
+
+@pytest.mark.parametrize("fidelity,pad_quantum", [(1.0, 1), (0.5, 64)])
+def test_build_fleet_batch_returns_the_one_pass_arrays(fidelity,
+                                                      pad_quantum):
+    from repro.fleet import FleetConfig, build_fleet_batch, grid_space
+    flash = FlashGeometry(n_channels=4, ways_per_channel=1,
+                          blocks_per_lun=16, pages_per_block=4,
+                          page_bytes=4096)
+    eng = E.ZoneEngine(flash, ZoneGeometry(4, 4),
+                       (SUPERBLOCK, BLOCK, vchunk(2)), max_active=6)
+    configs = grid_space(segments=(4, 2), chunks=(8,),
+                         parities=(False, True), wear=(True, False),
+                         specs=(SUPERBLOCK, BLOCK, vchunk(2)),
+                         policies=("traditional", "silent"))[::5]
+    # a narrower array squares up with pad lanes; a spec-mix array
+    configs += [FleetConfig("dlwa_pair", 4, 8, True, True,
+                            (BLOCK, vchunk(2)), n_devices=2)]
+    got = build_fleet_batch(eng, configs, n_devices=3, fidelity=fidelity,
+                            pad_quantum=pad_quantum)
+    want = _one_pass_build(eng, configs, n_devices=3, fidelity=fidelity,
+                           pad_quantum=pad_quantum)
+    np.testing.assert_array_equal(got[0], want[0])
+    for f in E.DynConfig._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(got[1], f)),
+                                      np.asarray(getattr(want[1], f)), f)
+    assert len(got[2]) == len(want[2]) == len(configs)
+    for g, w in zip(got[2], want[2]):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_build_fleet_batch_validates_every_config_before_building(
+        monkeypatch):
+    from repro.fleet import FleetConfig, build_fleet_batch, search
+    built = []
+    monkeypatch.setitem(search.MIXES, "counted",
+                        lambda eng, cap: built.append(cap) or [])
+    eng = tiny_engine(SUPERBLOCK)
+    good = FleetConfig("counted", 2, 8, False, True)
+    with pytest.raises(ValueError, match="not a member"):
+        build_fleet_batch(eng, [good, dataclasses.replace(
+            good, spec=BLOCK)], n_devices=2)
+    with pytest.raises(ValueError, match="n_segments exceeds"):
+        build_fleet_batch(eng, [good, dataclasses.replace(
+            good, n_segments=99)], n_devices=2)
+    assert built == []
