@@ -200,7 +200,12 @@ class DynConfig(NamedTuple):
     :class:`EngineConfig` fields.
 
     Every field is a rank-0 array (or, under ``run_programs``, a
-    ``(n_programs,)`` vector -- one value per lane):
+    ``(n_programs,)`` vector -- one value per lane).  Where the leaves
+    live: :func:`make_dyn` returns 0-d *numpy* arrays on the host, and
+    :func:`stack_dyn` stacks lanes there and moves the whole batch to
+    the device in one transfer, so a fleet of hundreds of lanes costs
+    one host-to-device copy instead of a device scalar per field and
+    lane (a single-lane dyn is transferred by ``jit`` at the call):
 
     * ``zone_pages``  -- () i32, effective zone capacity in *pages*.
       Must be ``<= cfg.zone_pages``; a smaller value emulates a
@@ -306,6 +311,10 @@ def make_dyn(cfg: EngineConfig, *, zone_pages: Optional[int] = None,
     ``wear_bound`` get the same treatment: an unknown policy or a
     negative bound would otherwise flow into the jitted selection as a
     silently-traditional lane or an always-empty claimable set.
+
+    The leaves are 0-d numpy arrays (``np.int32``; ``np.bool_`` for
+    ``wear_aware``): a lane's dyn stays on the host until
+    :func:`stack_dyn` (or ``jit``, for a single lane) transfers it.
     """
     if spec is not None:
         sv = cfg.member_values(spec)
@@ -360,24 +369,25 @@ def make_dyn(cfg: EngineConfig, *, zone_pages: Optional[int] = None,
         raise ValueError(
             f"wear_bound override {wear_bound} out of range "
             f"(must be in [0, {_BIG}])")
-    i32 = jnp.int32
+    i32 = np.int32
     return DynConfig(
-        zone_pages=jnp.asarray(
+        zone_pages=np.asarray(
             cfg.zone_pages if zone_pages is None else zone_pages, i32),
-        max_active=jnp.asarray(
+        max_active=np.asarray(
             cfg.max_active if max_active is None else max_active, i32),
-        n_zones=jnp.asarray(
+        n_zones=np.asarray(
             cfg.n_zones if n_zones is None else n_zones, i32),
-        wear_aware=jnp.asarray(
-            cfg.wear_aware if wear_aware is None else wear_aware, bool),
-        n_elements=jnp.asarray(sv.n_elements, i32),
-        per_group=jnp.asarray(sv.per_group, i32),
-        take=jnp.asarray(sv.take, i32),
-        zone_groups=jnp.asarray(sv.zone_groups, i32),
-        slot_stride=jnp.asarray(sv.slot_stride, i32),
-        pages_per_element=jnp.asarray(sv.pages_per_element, i32),
-        alloc_policy=jnp.asarray(policy, i32),
-        wear_bound=jnp.asarray(
+        wear_aware=np.asarray(
+            cfg.wear_aware if wear_aware is None else wear_aware,
+            np.bool_),
+        n_elements=np.asarray(sv.n_elements, i32),
+        per_group=np.asarray(sv.per_group, i32),
+        take=np.asarray(sv.take, i32),
+        zone_groups=np.asarray(sv.zone_groups, i32),
+        slot_stride=np.asarray(sv.slot_stride, i32),
+        pages_per_element=np.asarray(sv.pages_per_element, i32),
+        alloc_policy=np.asarray(policy, i32),
+        wear_bound=np.asarray(
             _BIG if wear_bound is None else wear_bound, i32),
     )
 
@@ -407,14 +417,29 @@ def dyn_values(cfg: EngineConfig, dyn: Optional[DynConfig] = None,
     return out
 
 
+_DYN_DTYPES = DynConfig(*(np.bool_ if f == "wear_aware" else np.int32
+                          for f in DynConfig._fields))
+
+
 def stack_dyn(dyns: Sequence[DynConfig]) -> DynConfig:
     """Stack per-lane :class:`DynConfig`\\ s along a leading batch axis
-    (the shape ``run_programs`` consumes for a heterogeneous batch)."""
+    (the shape ``run_programs`` consumes for a heterogeneous batch).
+
+    Each field is stacked with numpy on the host, in its
+    :class:`DynConfig` dtype, and the stacked tuple then reaches the
+    device in ONE ``jax.device_put``: stacking device scalars instead
+    costs a transfer per lane and field plus a device op per field,
+    seconds for a few hundred lanes against milliseconds here.  Leaves
+    may be numpy arrays (:func:`make_dyn`), ``jax.Array``\\ s or Python
+    scalars (``dyn._replace(...)``); the result's are ``jax.Array``\\ s.
+    """
     dyns = list(dyns)
     if not dyns:
         raise ValueError("stack_dyn needs at least one DynConfig "
                          "(an empty fleet batch has no lanes to stack)")
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *dyns)
+    host = DynConfig(*(np.stack([np.asarray(d[i], dt) for d in dyns])
+                       for i, dt in enumerate(_DYN_DTYPES)))
+    return jax.device_put(host)
 
 
 def _slot_stride(spec: ElementSpec, parallelism: int) -> int:

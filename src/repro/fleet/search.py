@@ -307,7 +307,11 @@ def build_fleet_batch(eng: ZoneEngine, configs: Sequence[FleetConfig],
 
     Every config is validated before anything is built.  Under a
     current profiler the op rows are timed as ``build.lanes`` and the
-    dyns as ``build.dyn``.
+    dyns as ``build.dyn``.  Each lane's dyn stays on the host (numpy
+    leaves, see :func:`~repro.core.engine.make_dyn`) and the stacked
+    batch reaches the device in one transfer in
+    :func:`~repro.core.engine.stack_dyn`: building hundreds of lanes
+    as device scalars cost seconds a call.
     """
     if not 0.0 < fidelity <= 1.0:
         raise ValueError(f"fidelity must be in (0, 1], got {fidelity}")

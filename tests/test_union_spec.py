@@ -265,6 +265,112 @@ def test_build_fleet_batch_rejects_non_member_spec():
         build_fleet_batch(UNION, [fc], n_devices=3)
 
 
+# --------------------------------------------------------------------- #
+# where a fleet batch's DynConfig lives: host leaves, one transfer
+# --------------------------------------------------------------------- #
+def _hetero_configs():
+    """Mixed specs (incl. a per-member spec tuple), both policies, both
+    allocators, and member counts 2 and 3, so the batch has pad lanes."""
+    from repro.fleet import FleetConfig
+
+    return [FleetConfig("dlwa_pair", 4, 8, True, True, BLOCK,
+                        n_devices=3, alloc_policy="silent"),
+            FleetConfig("dlwa_write", 2, 16, False, False, SUPERBLOCK,
+                        n_devices=2),
+            FleetConfig("dlwa_pair", 2, 8, False, True,
+                        (vchunk(2), BLOCK), n_devices=3,
+                        alloc_policy="silent"),
+            FleetConfig("dlwa_write", 4, 8, True, False, vchunk(2),
+                        n_devices=2)]
+
+
+def test_build_fleet_batch_dyn_equals_device_scalar_stack():
+    """The host-built stacked dyn equals, leaf by leaf in value, dtype
+    and shape, the construction it replaces: every lane's fields as
+    device scalars (``jnp.asarray``), then ``jnp.stack`` per field."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from repro.fleet import build_fleet_batch
+
+    configs = _hetero_configs()
+    _, dyn, _ = build_fleet_batch(UNION, configs, n_devices=3)
+    seg_pages = ZGEOM.parallelism * FLASH.pages_per_block
+    lanes = []
+    for fc in configs:
+        mix = fc.specs_mix()
+        for d in range(fc.n_devices):
+            spec = mix[d % len(mix)]
+            sv = UNION.cfg.member_values(spec)
+            lanes.append(dict(
+                zone_pages=seg_pages * fc.n_segments,
+                max_active=UNION.cfg.max_active,
+                n_zones=UNION.cfg.n_zones, wear_aware=fc.wear_aware,
+                **dataclasses.asdict(sv),
+                alloc_policy=(E.POLICY_SILENT
+                              if fc.alloc_policy == "silent"
+                              else E.POLICY_TRADITIONAL),
+                wear_bound=E._BIG))
+        lanes += [None] * (3 - fc.n_devices)    # pad lanes: defaults
+    assert len(lanes) == 3 * len(configs) and None in lanes
+    default = E.dyn_values(UNION.cfg)
+    old = [E.DynConfig(**{
+        f: jnp.asarray(v, bool if f == "wear_aware" else jnp.int32)
+        for f, v in (lane or default).items()}) for lane in lanes]
+    old = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *old)
+    for f, new_leaf, old_leaf in zip(E.DynConfig._fields, dyn, old):
+        assert isinstance(new_leaf, jax.Array), f
+        assert new_leaf.dtype == old_leaf.dtype, f
+        assert new_leaf.shape == old_leaf.shape == (len(lanes),), f
+        assert np.array_equal(np.asarray(new_leaf),
+                              np.asarray(old_leaf)), f
+
+
+def test_make_dyn_host_leaves_and_stack_dyn_device_leaves():
+    """``make_dyn`` leaves are 0-d numpy arrays of the field's dtype;
+    ``stack_dyn`` returns ``jax.Array`` leaves and still takes
+    ``_replace``d Python-int and ``jax.Array`` leaves in the field's
+    dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    d = UNION.dyn(spec=BLOCK, alloc_policy="silent", wear_bound=3)
+    for f, leaf in zip(E.DynConfig._fields, d):
+        assert isinstance(leaf, np.ndarray) and leaf.shape == (), f
+        assert leaf.dtype == (np.bool_ if f == "wear_aware"
+                              else np.int32), f
+    assert int(d.alloc_policy) == E.POLICY_SILENT
+    assert int(d.wear_bound) == 3
+    assert int(d.n_elements) == UNION.cfg.member_values(BLOCK).n_elements
+    mixed = [d, d._replace(wear_bound=5, wear_aware=False),
+             d._replace(zone_pages=jnp.asarray(HALF, jnp.int32))]
+    st_ = E.stack_dyn(mixed)
+    for f, leaf in zip(E.DynConfig._fields, st_):
+        assert isinstance(leaf, jax.Array) and leaf.shape == (3,), f
+        assert leaf.dtype == (jnp.bool_ if f == "wear_aware"
+                              else jnp.int32), f
+    assert np.asarray(st_.wear_bound).tolist() == [3, 5, 3]
+    assert np.asarray(st_.wear_aware).tolist() == [True, False, True]
+    assert int(st_.zone_pages[2]) == HALF
+    assert E.dyn_values(UNION.cfg, st_, lane=1)["wear_bound"] == 5
+
+
+def test_evaluator_repeats_do_not_grow_run_programs_cache():
+    """Host-built dyns reach ``run_programs`` with one abstract
+    signature: repeated same-shape dispatches of a batch with pad lanes
+    compile once."""
+    from repro.fleet import Evaluator
+
+    ev = Evaluator(UNION, n_devices=3, pad_quantum=64)
+    configs = _hetero_configs()
+    first = ev.evaluate(configs)
+    cache = ev.jit_cache()
+    for _ in range(2):
+        assert ev.evaluate(configs) == first
+        assert ev.jit_cache() == cache
+
+
 def test_search_space_spec_axis_codec():
     from repro.fleet import SearchSpace
 
