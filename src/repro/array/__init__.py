@@ -16,7 +16,8 @@ the object ``ZNSArray`` kept as the bit-exactness oracle.
 
 from repro.array.engine import (ArrayEngine, ArrayResult,
                                 array_vs_legacy_speedup, apply_commands,
-                                fill_commands, run_array_batch,
+                                fill_commands, merge_rebuild,
+                                plan_rebuild, run_array_batch,
                                 run_array_timing)
 from repro.array.raid import (ArrayGeometry, SuperZoneInfo, TaggedTrace,
                               ZNSArray, data_device_of, locate_page,
@@ -26,5 +27,6 @@ from repro.array.storm import StormScenario, rebuild_storm
 __all__ = ["ArrayEngine", "ArrayGeometry", "ArrayResult", "StormScenario",
            "SuperZoneInfo", "TaggedTrace", "ZNSArray", "apply_commands",
            "array_vs_legacy_speedup", "data_device_of", "fill_commands",
-           "locate_page", "member_chunk_pages", "parity_device_of",
-           "rebuild_storm", "run_array_batch", "run_array_timing"]
+           "locate_page", "member_chunk_pages", "merge_rebuild",
+           "parity_device_of", "plan_rebuild", "rebuild_storm",
+           "run_array_batch", "run_array_timing"]

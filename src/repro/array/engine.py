@@ -176,11 +176,11 @@ class ArrayEngine:
                     f"engine's config; build the engine over the spec "
                     f"set")
         self.member_specs = member_specs
-        # per-member wear_aware / alloc_policy: a rebuilt member is a
-        # stock blank device (the object array's replacement drops the
-        # overrides).  Note the bit-exactness oracle (the object
-        # ZNSArray) has no silent allocator, so wear rollups are only
-        # cross-checked against it when alloc_policy is unset.
+        # per-member wear_aware / alloc_policy (a rebuilt member keeps
+        # them, as the object array's replacement keeps wear_aware).
+        # Note the bit-exactness oracle (the object ZNSArray) has no
+        # silent allocator, so wear rollups are only cross-checked
+        # against it when alloc_policy is unset.
         self._member_wear_aware: List[Optional[bool]] = (
             [wear_aware] * geom.n_devices)
         self._member_alloc_policy: List[Optional[str]] = (
@@ -432,12 +432,15 @@ class ArrayEngine:
 
         The failed lane's program is *replaced* by the reconstructed
         append stream (the replacement starts blank, exactly like the
-        object array's fresh ``ZNSDevice``); every chunk row it held is
+        object array's fresh member); every chunk row it held is
         re-read from the surviving members that wrote it (stripe XOR --
         the degraded-read access pattern) as state-neutral ``OP_READ``
-        rows on their lanes.  Nothing executes until :meth:`run`; the
-        whole rebuild then rides the same single dispatch as the rest
-        of the array's history.
+        rows on their lanes.  The plan is :func:`plan_rebuild`'s, the
+        one the fleet builder compiles a member failure with.  Nothing
+        executes until :meth:`run`; the whole rebuild then rides the
+        same single dispatch as the rest of the array's history.  The
+        replacement keeps the member's spec, allocator and mapping
+        policy: a deployment replaces a drive with the same model.
 
         Returns the read plan as ``(survivor, zone, offset, n_read)``
         tuples (what the object array's tagged traces realize).
@@ -446,43 +449,20 @@ class ArrayEngine:
             raise RuntimeError("rebuild requires parity")
         if any(f != idx for f in self.failed):
             raise RuntimeError("cannot rebuild with another member down")
-        c = self.geom.chunk_pages
         new_rows: List[tuple] = []
         plan: List[Tuple[int, int, int, int]] = []
-        for z, info in self.zones.items():
-            if info.wp == 0 and info.parity_emitted == 0:
-                continue
-            dwp = {other: self.member_wp(z, other)
-                   for other in range(self.geom.n_devices)}
-            wrote = 0
-            for s in range(self.stripes_per_zone):
-                pages_here = self._member_chunk(z, s, idx, info)
-                if pages_here <= 0:
-                    continue
-                off = s * c
-                for other in range(self.geom.n_devices):
-                    if other == idx or other in self.failed:
-                        continue
-                    if dwp[other] <= off:
-                        continue
-                    n_read = min(pages_here, dwp[other] - off)
-                    self._rows[other].append(
-                        (zengine.OP_READ, z, n_read, 0,
-                         self.rebuild_tenant))
-                    plan.append((other, z, off, n_read))
-                new_rows.append(
-                    (zengine.OP_WRITE, z, pages_here, zengine.F_HOST,
-                     self.rebuild_tenant))
-                wrote += pages_here
-            if info.state is ZoneState.FULL and wrote > 0:
-                new_rows.append(
-                    (zengine.OP_FINISH, z, 0, 0, self.rebuild_tenant))
+        for member, op, z, off, n in plan_rebuild(
+                self.zones, idx, chunk_pages=self.geom.chunk_pages,
+                n_devices=self.geom.n_devices,
+                stripes_per_zone=self.stripes_per_zone):
+            row = (op, z, n, zengine.F_HOST if op == zengine.OP_WRITE
+                   else 0, self.rebuild_tenant)
+            if member == idx:
+                new_rows.append(row)
+            else:
+                self._rows[member].append(row)
+                plan.append((member, z, off, n))
         self._rows[idx] = new_rows
-        # the replacement is a stock device: the object array builds it
-        # without the wear_aware / alloc_policy overrides, so the
-        # oracle does too
-        self._member_wear_aware[idx] = None
-        self._member_alloc_policy[idx] = None
         self.failed.discard(idx)
         self._dirty = True
         return plan
@@ -615,6 +595,93 @@ class ArrayEngine:
             out[f"tenant{t}_makespan_s"] = (
                 float(completions[sel].max()) if sel.any() else 0.0)
         return out
+
+
+# --------------------------------------------------------------------- #
+# rebuild planning + merging (shared with the fleet builder)
+# --------------------------------------------------------------------- #
+def plan_rebuild(zones: Dict[int, SuperZoneInfo], idx: int, *,
+                 chunk_pages: int, n_devices: int, stripes_per_zone: int
+                 ) -> List[Tuple[int, int, int, int, int]]:
+    """The rebuild of member ``idx`` of a RAID-5 array (parity on) from
+    its superzone metadata alone: ``(member, opcode, zone, offset,
+    n_pages)`` steps in order.
+
+    Superzones are taken in zone order.  For every chunk row member
+    ``idx`` physically held, each surviving member that wrote that row
+    reads it (``OP_READ`` at its member offset), then the reconstructed
+    chunk is appended to the replacement (``OP_WRITE``, member
+    ``idx``); a FULL superzone the replacement wrote into is FINISHed
+    there.  The object :class:`ZNSArray`'s ``rebuild_device`` walks the
+    same plan over its members' write pointers (the oracle)."""
+    c = chunk_pages
+    kw = dict(chunk_pages=c, n_data=n_devices - 1, n_devices=n_devices,
+              parity=True)
+    steps: List[Tuple[int, int, int, int, int]] = []
+    for z in sorted(zones):
+        info = zones[z]
+        if info.wp == 0 and info.parity_emitted == 0:
+            continue
+        chunk = [[member_chunk_pages(z, s, d, wp=info.wp,
+                                     parity_emitted=info.parity_emitted,
+                                     **kw)
+                  for s in range(stripes_per_zone)]
+                 for d in range(n_devices)]
+        dwp = [sum(rows) for rows in chunk]
+        wrote = 0
+        for s in range(stripes_per_zone):
+            pages_here = chunk[idx][s]
+            if pages_here <= 0:
+                continue
+            off = s * c
+            for other in range(n_devices):
+                if other != idx and dwp[other] > off:
+                    steps.append((other, zengine.OP_READ, z, off,
+                                  min(pages_here, dwp[other] - off)))
+            steps.append((idx, zengine.OP_WRITE, z, off, pages_here))
+            wrote += pages_here
+        if info.state is ZoneState.FULL and wrote > 0:
+            steps.append((idx, zengine.OP_FINISH, z, 0, 0))
+    return steps
+
+
+def merge_rebuild(foreground: Sequence[tuple], rebuild: Sequence[tuple],
+                  *, replacement: bool) -> Tuple[List[tuple], int]:
+    """Round-robin interleave of one member lane's foreground rows and
+    its rebuild rows (concurrent submission queues, the merge model
+    ``timing`` uses for traces), foreground first.
+
+    One ordering rule holds back a foreground row of zone ``z`` while
+    rebuild rows of ``z`` are still queued on the lane: a RESET of
+    ``z``, and on the ``replacement`` any row of ``z`` (its data must
+    be back before the zone takes new appends, a FINISH or a RESET).
+    The queued rebuild rows, in order, go first.  Returns the merged
+    rows and how many foreground rows were held."""
+    pending: Dict[int, int] = {}
+    for row in rebuild:
+        pending[row[1]] = pending.get(row[1], 0) + 1
+    out: List[tuple] = []
+    j, held = 0, 0
+
+    def take() -> None:
+        nonlocal j
+        row = rebuild[j]
+        out.append(row)
+        pending[row[1]] -= 1
+        j += 1
+
+    for row in foreground:
+        z = row[1]
+        if pending.get(z) and (replacement or row[0] == zengine.OP_RESET):
+            held += 1
+            while pending[z]:
+                take()
+        out.append(row)
+        if j < len(rebuild):
+            take()
+    while j < len(rebuild):
+        take()
+    return out, held
 
 
 # --------------------------------------------------------------------- #

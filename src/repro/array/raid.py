@@ -393,8 +393,9 @@ class ZNSArray:
             parity_emitted=info.parity_emitted)
 
     def rebuild_device(self, idx: int) -> List[TaggedTrace]:
-        """Replace member ``idx`` with a blank device and reconstruct its
-        chunks (data *and* rotated parity) from the survivors.
+        """Replace member ``idx`` with a blank device of the same kind,
+        spec and allocator, and reconstruct its chunks (data *and*
+        rotated parity) from the survivors.
 
         For every chunk row the lost member held, the same row is read
         from each surviving member that wrote it (stripe XOR, exactly the
@@ -413,8 +414,10 @@ class ZNSArray:
         if any(f != idx for f in self.failed):
             raise RuntimeError("cannot rebuild with another member down")
         old = self.devices[idx]
-        replacement = ZNSDevice(old.flash, old.zone_geom, old.spec,
-                                max_active=old.max_active)
+        # the same model and firmware: spec, active limit and allocator
+        replacement = type(old)(old.flash, old.zone_geom, old.spec,
+                                max_active=old.max_active,
+                                wear_aware=old.wear_aware)
         c = self.geom.chunk_pages
         tagged: List[TaggedTrace] = []
         for z, info in self.zones.items():

@@ -30,8 +30,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.array.engine import (ArrayEngine, run_array_batch,
-                                run_array_timing)
+from repro.array.engine import (ArrayEngine, merge_rebuild,
+                                run_array_batch, run_array_timing)
 from repro.array.raid import ArrayGeometry
 from repro.core import engine as zengine
 from repro.core.elements import ElementSpec
@@ -61,18 +61,6 @@ class StormScenario:
                 and len(set(self.member_specs)) > 1 else "uniform")
         return (f"d{self.n_devices}_c{self.chunk_pages or 'seg'}_"
                 f"z{self.n_zones_filled}_o{self.occupancy:g}_{spec}")
-
-
-def _rr_merge(a: List[tuple], b: List[tuple]) -> List[tuple]:
-    """Round-robin interleave of two op-row streams (the concurrent
-    submission-queue model ``timing`` uses to merge traces)."""
-    out: List[tuple] = []
-    for i in range(max(len(a), len(b))):
-        if i < len(a):
-            out.append(a[i])
-        if i < len(b):
-            out.append(b[i])
-    return out
 
 
 def _build_variant(eng: ZoneEngine, sc: StormScenario, *,
@@ -116,7 +104,8 @@ def _build_variant(eng: ZoneEngine, sc: StormScenario, *,
             prefix = rows[: marks[lane]]
             reb = rows[marks[lane]: post_rebuild[lane]]
             hst = rows[post_rebuild[lane]:]
-            a._rows[lane] = prefix + _rr_merge(hst, reb)
+            a._rows[lane] = prefix + merge_rebuild(
+                hst, reb, replacement=lane == failed)[0]
     return a, marks
 
 

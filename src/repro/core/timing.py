@@ -32,7 +32,7 @@ int32 indexes).
 from __future__ import annotations
 
 import functools
-from typing import Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -125,7 +125,8 @@ def simulate_fleet(ops: jax.Array, luns: jax.Array, channels: jax.Array,
 @functools.partial(jax.jit, static_argnames=("n_luns", "n_tenants"))
 def simulate_fleet_ops(cols: jax.Array, pages: jax.Array,
                        tenants: jax.Array, t_page: jax.Array,
-                       n_luns: int, n_tenants: int
+                       n_luns: int, n_tenants: int,
+                       ready: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Op-granular fleet timing: one batched scan over whole zone ops.
 
@@ -141,7 +142,11 @@ def simulate_fleet_ops(cols: jax.Array, pages: jax.Array,
 
     Tenant latency is closed-loop: a tenant issues its next op when its
     previous op completes, so ``latency = completion - previous
-    completion of the same tenant`` (queueing + service).
+    completion of the same tenant`` (queueing + service).  ``ready``
+    gives an op an earliest issue time (an event on another lane it
+    waits for, such as a member failure or the survivor reads a rebuilt
+    chunk is computed from): it then starts no earlier, and its latency
+    runs from the later of the two issue times.
 
     Args:
       cols:    (n_lanes, n_ops, P) int32 zone column -> LUN of each op
@@ -152,6 +157,7 @@ def simulate_fleet_ops(cols: jax.Array, pages: jax.Array,
                (n_lanes, n_ops) f32 per-op page cost (the array runner
                prices READ rows at ``t_read + t_xfer``).
       n_luns/n_tenants: static sizes.
+      ready:   optional (n_lanes, n_ops) f32 earliest issue time per op.
 
     Returns:
       (completions (n_lanes, n_ops) f32 with 0 on skipped ops,
@@ -166,18 +172,20 @@ def simulate_fleet_ops(cols: jax.Array, pages: jax.Array,
     dur = ((pages + P - 1) // P).astype(jnp.float32) * jnp.asarray(
         t_page, jnp.float32)
 
-    def one_lane(cols_l, pages_l, ten_l, dur_l):
+    def one_lane(cols_l, pages_l, ten_l, dur_l, *ready_l):
         def step(carry, x):
             lun_free, ten_done = carry
-            c, pg, t, dur = x
+            c, pg, t, dur, *rdy = x
             active = pg > 0
-            # an op starts when its LUN columns free up AND its tenant
-            # has completed its previous op (closed-loop issue)
+            # an op is issued when its tenant has completed its previous
+            # op (closed loop), and not before its ready time; it starts
+            # when its LUN columns free up too
+            issued = ten_done[t] if not rdy else jnp.maximum(
+                ten_done[t], rdy[0])
             start = jnp.maximum(
-                jnp.max(jnp.where(active, lun_free[c], 0.0)),
-                ten_done[t])
+                jnp.max(jnp.where(active, lun_free[c], 0.0)), issued)
             done = start + dur
-            lat = jnp.where(active, done - ten_done[t], 0.0)
+            lat = jnp.where(active, done - issued, 0.0)
             lun_free = lun_free.at[c].set(
                 jnp.where(active, done, lun_free[c]))
             ten_done = ten_done.at[t].set(
@@ -187,10 +195,11 @@ def simulate_fleet_ops(cols: jax.Array, pages: jax.Array,
         init = (jnp.zeros(n_luns, jnp.float32),
                 jnp.zeros(n_tenants, jnp.float32))
         (lun_free, _), (done, lat) = jax.lax.scan(
-            step, init, (cols_l, pages_l, ten_l, dur_l))
+            step, init, (cols_l, pages_l, ten_l, dur_l, *ready_l))
         return done, lat, jnp.max(lun_free)
 
-    return jax.vmap(one_lane)(cols, pages, tenants, dur)
+    extra = () if ready is None else (jnp.asarray(ready, jnp.float32),)
+    return jax.vmap(one_lane)(cols, pages, tenants, dur, *extra)
 
 
 def run_fleet_trace(flash: FlashGeometry,

@@ -31,11 +31,13 @@ evolve-vs-random dispatches-to-target comparison to
 
 from repro.fleet.evolve import (EvolveParams, EvolveResult, evolve,
                                 evolve_vs_random)
-from repro.fleet.runner import (FleetResult, assert_all_ok, config_report,
-                                dispatch_cost, real_op_count, run_fleet)
+from repro.fleet.runner import (FleetResult, Rebuilds, assert_all_ok,
+                                config_report, dispatch_cost,
+                                real_op_count, run_fleet)
 from repro.fleet.search import (MIXES, N_TENANTS, OBJECTIVE_KEYS,
-                                Evaluator, FleetConfig, SearchSpace,
-                                build_fleet_batch, evaluate_configs,
+                                Evaluator, FleetBatch, FleetConfig,
+                                SearchSpace, build_fleet_batch,
+                                evaluate_configs, fleet_batch,
                                 grid_space, pareto_front, random_space,
                                 run_configs_legacy, score_rows)
 from repro.fleet.tenants import (TENANT_COL, interleave_tenants,
@@ -43,10 +45,11 @@ from repro.fleet.tenants import (TENANT_COL, interleave_tenants,
 
 __all__ = [
     "EvolveParams", "EvolveResult", "evolve", "evolve_vs_random",
-    "FleetResult", "assert_all_ok", "config_report", "dispatch_cost",
-    "real_op_count", "run_fleet",
-    "MIXES", "N_TENANTS", "OBJECTIVE_KEYS", "Evaluator", "FleetConfig",
-    "SearchSpace", "build_fleet_batch", "evaluate_configs", "grid_space",
+    "FleetResult", "Rebuilds", "assert_all_ok", "config_report",
+    "dispatch_cost", "real_op_count", "run_fleet",
+    "MIXES", "N_TENANTS", "OBJECTIVE_KEYS", "Evaluator", "FleetBatch",
+    "FleetConfig", "SearchSpace", "build_fleet_batch", "evaluate_configs",
+    "fleet_batch", "grid_space",
     "pareto_front", "random_space", "run_configs_legacy", "score_rows",
     "TENANT_COL", "interleave_tenants", "pad_programs",
     "stripe_program", "tag_tenant",

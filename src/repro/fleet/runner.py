@@ -17,7 +17,7 @@ erase-block erasures, times are seconds.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -30,14 +30,34 @@ from repro.fleet.tenants import TENANT_COL
 from repro.obs.profile import span
 
 
+class Rebuilds(NamedTuple):
+    """The member rebuilds of a fleet batch (the ``rebuilds`` of
+    :func:`repro.fleet.search.fleet_batch` when a config fails): what
+    the timing and the recovery rollups read.
+
+    Each array with a failure has one *failure instant*: the latest
+    completion of a row its lanes issued before the failure.  No later
+    row of the array starts before it, and a chunk appended to the
+    replacement waits for the survivor reads it is computed from.
+    """
+
+    tenant: int          # tag of the rebuild rows (parity tag + 1)
+    marks: np.ndarray    # (L,) rows issued before the failure; -1 on
+                         #   lanes of healthy arrays and pad lanes
+    group: np.ndarray    # (L,) the array (config) each lane belongs to
+    waits: np.ndarray    # (K, 4) lane, row, source lane, source row: a
+                         #   row issued once its source row completed
+
+
 @dataclasses.dataclass
 class FleetResult:
     """Per-lane outputs of one batched fleet dispatch (all numpy).
 
     Lane axis ``L`` = flattened (config, device); op axis is the padded
     program length.  ``tenants`` holds the width-5 tenant column;
-    parity appends carry ``parity_tenant``; NOP padding moves 0 pages
-    and is ignored by every rollup.
+    parity appends carry ``parity_tenant``, a member rebuild's rows
+    ``rebuilds.tenant`` (``rebuilds`` is None when no lane rebuilds);
+    NOP padding moves 0 pages and is ignored by every rollup.
     """
 
     programs: np.ndarray     # (L, n_ops, 5) i32
@@ -63,6 +83,7 @@ class FleetResult:
     #: repro.check verifier and name the predicted error class
     cfg: Optional[zengine.EngineConfig] = None
     dyn: Optional[DynConfig] = None
+    rebuilds: Optional[Rebuilds] = None
 
     @property
     def tenants(self) -> np.ndarray:
@@ -162,7 +183,8 @@ class FleetResult:
 
 def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
               dyn: Optional[DynConfig] = None, n_tenants: int = 1,
-              parity_tenant: Optional[int] = None, obs=None,
+              parity_tenant: Optional[int] = None,
+              rebuilds: Optional[Rebuilds] = None, obs=None,
               profiler=None) -> FleetResult:
     """Execute ``(L, n_ops, 5)`` fleet lanes in one batched dispatch.
 
@@ -172,6 +194,10 @@ def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
     each executed op occupies its zone's LUN columns for
     ``ceil(pages / P) * (t_prog + t_xfer)`` seconds; deferred-erase
     latency is not modeled (it is tracked as ``erase_delta`` instead).
+    Tenant tags run up to ``parity_tenant``.  A batch holding member
+    rebuilds passes ``rebuilds``: their rows are one more closed-loop
+    stream per lane, and the clock runs three times (see
+    :func:`_rebuild_clock`); healthy batches keep their timing shape.
 
     ``obs`` (a ``repro.obs.ObsConfig``) threads the in-scan telemetry
     recorder through the dispatch; the result then carries per-lane
@@ -221,22 +247,60 @@ def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
             op == zengine.OP_READ,
             np.float32(eng.flash.t_read + eng.flash.t_xfer),
             np.float32(eng.flash.t_prog + eng.flash.t_xfer))
-        completions, latencies, makespans = timing.simulate_fleet_ops(
-            cols, pages.astype(np.int32),
-            programs[:, :, TENANT_COL], t_page,
-            eng.flash.n_luns, parity_tenant + 1)
+        args = (cols, pages.astype(np.int32), programs[:, :, TENANT_COL],
+                t_page, eng.flash.n_luns)
+        if rebuilds is None:
+            completions, latencies, makespans = timing.simulate_fleet_ops(
+                *args, parity_tenant + 1)
+        else:
+            completions, latencies, makespans = _rebuild_clock(
+                *args, rebuilds)
         if timed is not None:
             jax.block_until_ready(completions)
     with span("fleet.decode", profiler):
         return _decode_fleet(programs, states, trace, dummy, pages, cols,
                              completions, latencies, makespans,
                              n_tenants, parity_tenant, elem_mask,
-                             telemetry, eng.cfg, dyn)
+                             telemetry, eng.cfg, dyn, rebuilds)
+
+
+def _rebuild_clock(cols, pages, tenants, t_page, n_luns: int,
+                   rebuilds: Rebuilds):
+    """``simulate_fleet_ops`` over a batch holding member rebuilds.
+
+    Lanes keep separate clocks, so the failure's order across lanes is
+    imposed by ready times, in three runs of the clock: the first
+    times the rows issued before each failure (nothing before them
+    changes later) and so each array's failure instant; the second
+    holds every later row of the array to that instant, which times
+    the survivors (no row of theirs waits for another lane); the third
+    holds each chunk appended to the replacement until the survivor
+    reads it is computed from have completed."""
+    n_tenants = rebuilds.tenant + 1
+    marks, group = rebuilds.marks, rebuilds.group
+    failed = marks >= 0
+    after = np.arange(pages.shape[1])[None, :] >= marks[:, None]
+    ready = np.zeros(pages.shape, np.float32)
+    done = np.asarray(timing.simulate_fleet_ops(
+        cols, pages, tenants, t_page, n_luns, n_tenants, ready)[0])
+    instant = np.zeros(int(group.max(initial=-1)) + 1, np.float32)
+    np.maximum.at(instant, group[failed],
+                  np.where(after, np.float32(0), done)[failed].max(axis=1))
+    ready = np.where(failed[:, None] & after,
+                     instant[np.maximum(group, 0)][:, None],
+                     np.float32(0)).astype(np.float32)
+    done = np.asarray(timing.simulate_fleet_ops(
+        cols, pages, tenants, t_page, n_luns, n_tenants, ready)[0])
+    lane, row, src_lane, src_row = np.asarray(rebuilds.waits).T
+    np.maximum.at(ready, (lane, row), done[src_lane, src_row])
+    return timing.simulate_fleet_ops(cols, pages, tenants, t_page, n_luns,
+                                     n_tenants, ready)
 
 
 def _decode_fleet(programs, states, trace, dummy, pages, cols, completions,
                   latencies, makespans, n_tenants, parity_tenant,
-                  elem_mask, telemetry, cfg=None, dyn=None) -> FleetResult:
+                  elem_mask, telemetry, cfg=None, dyn=None,
+                  rebuilds=None) -> FleetResult:
     return FleetResult(
         programs=programs,
         states=states,
@@ -255,6 +319,7 @@ def _decode_fleet(programs, states, trace, dummy, pages, cols, completions,
         telemetry=telemetry,
         cfg=cfg,
         dyn=dyn,
+        rebuilds=rebuilds,
     )
 
 
@@ -269,6 +334,19 @@ def config_report(res: FleetResult, eng: ZoneEngine,
     * ``p99_latency_s``: worst real tenant's p99 closed-loop latency;
     * ``makespan_s``: slowest member (the fleet completes a stripe only
       when every chunk is durable).
+
+    A config whose member failed and was rebuilt (its lanes' marks in
+    ``res.rebuilds``) also gets, timed as ``fleet.recover``:
+    ``recover_s`` (the rebuild's last completion less the failure
+    instant, the latest completion of a row issued before the failure
+    over its lanes; 0 without rebuild rows),
+    ``rebuild_pages`` (pages the rebuild appended to the replacement),
+    ``tenant<k>_p99_after_failure_s`` per real tenant and
+    ``member<d>_dlwa`` per member (its host + padding pages over its
+    host pages, parity and rebuild appends included).  The keys above
+    stay computed over the lanes as they ran: rebuild appends count as
+    host pages of the replacement, and the failed member's rows from
+    before the failure are gone with it.
     """
     with span("fleet.rollup"):
         lanes = np.asarray(lanes)
@@ -280,7 +358,7 @@ def config_report(res: FleetResult, eng: ZoneEngine,
         wear = res.pooled_wear(eng, lanes)
         mean_w = float(wear.mean()) if wear.size else 0.0
         p99 = res.tenant_p99_latency(lanes)
-        return {
+        out = {
             "host_pages": float(host),
             "parity_pages": float(par),
             "dummy_pages": float(dummy),
@@ -293,6 +371,38 @@ def config_report(res: FleetResult, eng: ZoneEngine,
             "makespan_s": float(res.makespans[lanes].max()),
             "ops_ok": float(res.ok[lanes].sum()),
         }
+        if (res.rebuilds is not None
+                and (res.rebuilds.marks[lanes] >= 0).any()):
+            with span("fleet.recover"):
+                out.update(_recovery(res, lanes, t))
+        return out
+
+
+def _recovery(res: FleetResult, lanes: np.ndarray,
+              t: np.ndarray) -> Dict[str, float]:
+    """``config_report``'s time to recover, post-failure tenant p99s
+    and per-member DLWA."""
+    done = res.completions[lanes]
+    after = (np.arange(done.shape[1])[None, :]
+             >= res.rebuilds.marks[lanes][:, None])
+    rb = t == res.rebuilds.tenant
+    out: Dict[str, float] = {
+        "recover_s": 0.0,
+        "rebuild_pages": float(int(res.host_delta[lanes][rb].sum()))}
+    if rb.any():
+        gap = done[rb].max() - done[~after].max(initial=np.float32(0))
+        out["recover_s"] = float(max(gap, np.float32(0)))
+    lat = res.latencies[lanes]
+    act = after & (res.pages[lanes] > 0)
+    for k in range(res.n_tenants):
+        sel = act & (t == k)
+        out[f"tenant{k}_p99_after_failure_s"] = (
+            float(np.percentile(lat[sel], 99)) if sel.any() else 0.0)
+    host = np.asarray(res.states.host_pages)[lanes]
+    dummy = np.asarray(res.states.dummy_pages)[lanes]
+    for d, (h, pad) in enumerate(zip(host.tolist(), dummy.tolist())):
+        out[f"member{d}_dlwa"] = (h + pad) / h if h else 1.0
+    return out
 
 
 def dispatch_cost(res: FleetResult) -> int:

@@ -22,6 +22,9 @@ space.  This module makes that search executable: a
 * **chunk size**      -- the RAID stripe unit (pages per member turn);
 * **parity**          -- log-structured RAID-5 parity on/off;
 * **allocator**       -- wear-aware vs first-fit element selection;
+* **failure**         -- optionally, one member failing at a share of
+                         the merged stream and rebuilt under the rest
+                         of it (:func:`repro.fleet.tenants.stripe_rebuild`);
 
 and every config expands to ``n_devices`` lanes that execute in ONE
 ``run_programs`` dispatch (:func:`evaluate_configs`).  Configs are
@@ -47,7 +50,8 @@ import dataclasses
 import itertools
 import math
 import random as pyrandom
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -58,7 +62,8 @@ from repro.core.engine import ZoneEngine, stack_dyn
 from repro.core.geometry import FlashGeometry, ZoneGeometry
 from repro.fleet import runner
 from repro.fleet.tenants import (interleave_tenants, pad_programs,
-                                 stripe_program, tag_tenant)
+                                 stripe_program, stripe_rebuild,
+                                 tag_tenant)
 from repro.obs.profile import span
 
 #: real tenants per mix (parity appends carry the tag N_TENANTS)
@@ -126,6 +131,11 @@ class FleetConfig:
     through the union config).  ``n_devices = 0`` means "the
     evaluator's default member count" -- the backward-compatible value
     every pre-array config carries.
+
+    ``failure = (member, at)`` fails member ``member`` before row
+    ``int(at * n_rows)`` of the merged logical stream and rebuilds it
+    under the rest (parity on only); ``None``, the default, is a
+    healthy array.
     """
 
     mix: str             # tenant mix (MIXES key)
@@ -136,6 +146,7 @@ class FleetConfig:
     spec: ElementSpec = SUPERBLOCK  # element granularity (or a mix tuple)
     n_devices: int = 0   # array member count (0 = evaluator default)
     alloc_policy: str = "traditional"  # zone mapping: traditional|silent
+    failure: Optional[Tuple[int, float]] = None  # (member, share) or None
 
     def specs_mix(self) -> Tuple[ElementSpec, ...]:
         """The spec tuple member ``d`` indexes with ``d % len``."""
@@ -155,6 +166,8 @@ class FleetConfig:
             base += f"_d{self.n_devices}"
         if self.alloc_policy != "traditional":
             base += f"_{self.alloc_policy}"
+        if self.failure is not None:
+            base += f"_fail{self.failure[0]}at{self.failure[1]:g}"
         return base
 
 
@@ -177,6 +190,7 @@ class SearchSpace:
     specs: Tuple = (SUPERBLOCK,)   # each entry: a spec, or a mix tuple
     devices: Tuple[int, ...] = (0,)  # member counts (0 = default)
     policies: Tuple[str, ...] = ("traditional",)  # alloc_policy values
+    failures: Tuple = (None,)      # failure values (None = healthy)
 
     @property
     def _axes_fields(self) -> Tuple[Tuple[Tuple, str], ...]:
@@ -186,7 +200,8 @@ class SearchSpace:
         # before those axes stay bit-identical.  Genes map to configs
         # by *field name* (not position): with policies present but
         # devices absent, a positional FleetConfig(*vals) would land
-        # the policy in n_devices.
+        # the policy in n_devices.  The failures axis joins the same
+        # way.
         base = [(self.mixes, "mix"), (self.segments, "n_segments"),
                 (self.chunks, "chunk_pages"), (self.parities, "parity"),
                 (self.wear, "wear_aware"), (self.specs, "spec")]
@@ -194,6 +209,8 @@ class SearchSpace:
             base.append((self.devices, "n_devices"))
         if self.policies != ("traditional",):
             base.append((self.policies, "alloc_policy"))
+        if self.failures != (None,):
+            base.append((self.failures, "failure"))
         return tuple(base)
 
     @property
@@ -222,6 +239,10 @@ class SearchSpace:
                 f"{fc.describe()}: config sets alloc_policy "
                 f"{fc.alloc_policy!r} but this space has no policies "
                 f"axis")
+        if fc.failure is not None and self.failures == (None,):
+            raise ValueError(
+                f"{fc.describe()}: config sets a failure but this space "
+                f"has no failures axis")
         return tuple(axis.index(getattr(fc, f))
                      for axis, f in self._axes_fields)
 
@@ -243,12 +264,14 @@ def grid_space(*, mixes: Sequence[str] = tuple(MIXES),
                wear: Sequence[bool] = (True, False),
                specs: Sequence = (SUPERBLOCK,),
                devices: Sequence[int] = (0,),
-               policies: Sequence[str] = ("traditional",)
+               policies: Sequence[str] = ("traditional",),
+               failures: Sequence = (None,)
                ) -> List[FleetConfig]:
     """Full cross product (defaults: 2*2*2*2*2 = 32 configs on zn540)."""
     return SearchSpace(tuple(mixes), tuple(segments), tuple(chunks),
                        tuple(parities), tuple(wear), tuple(specs),
-                       tuple(devices), tuple(policies)).grid()
+                       tuple(devices), tuple(policies),
+                       tuple(failures)).grid()
 
 
 def random_space(seed: int, n: int, *,
@@ -259,13 +282,15 @@ def random_space(seed: int, n: int, *,
                  wear: Sequence[bool] = (True, False),
                  specs: Sequence = (SUPERBLOCK,),
                  devices: Sequence[int] = (0,),
-                 policies: Sequence[str] = ("traditional",)
+                 policies: Sequence[str] = ("traditional",),
+                 failures: Sequence = (None,)
                  ) -> List[FleetConfig]:
     """``n`` distinct configs sampled without replacement from the grid
     by a seeded PRNG -- deterministic under a fixed seed (tested)."""
     grid = grid_space(mixes=mixes, segments=segments, chunks=chunks,
                       parities=parities, wear=wear, specs=specs,
-                      devices=devices, policies=policies)
+                      devices=devices, policies=policies,
+                      failures=failures)
     rng = np.random.default_rng(seed)
     idx = rng.choice(len(grid), size=min(n, len(grid)), replace=False)
     return [grid[i] for i in idx]
@@ -278,14 +303,34 @@ def _nd_max(configs: Sequence[FleetConfig], default: int) -> int:
                default=default)
 
 
+class FleetBatch(NamedTuple):
+    """The rectangular lane batch of one dispatch (:func:`fleet_batch`)."""
+
+    programs: np.ndarray              # (K*nd_max, n_ops, 5)
+    dyn: object                       # DynConfig with (K*nd_max,) leaves
+    merged: List[np.ndarray]          # merged logical program per config
+    rebuilds: Optional[runner.Rebuilds]  # None unless a config fails
+
+
 def build_fleet_batch(eng: ZoneEngine, configs: Sequence[FleetConfig],
                       *, n_devices: int, fidelity: float = 1.0,
                       pad_quantum: int = 1
                       ) -> Tuple[np.ndarray, object, List[np.ndarray]]:
+    """``(programs, dyn, merged)`` of :func:`fleet_batch`, the three
+    values its callers unpack (timing a failed config needs
+    ``fleet_batch``'s ``rebuilds`` too)."""
+    return fleet_batch(eng, configs, n_devices=n_devices,
+                       fidelity=fidelity, pad_quantum=pad_quantum)[:3]
+
+
+def fleet_batch(eng: ZoneEngine, configs: Sequence[FleetConfig], *,
+                n_devices: int, fidelity: float = 1.0,
+                pad_quantum: int = 1) -> FleetBatch:
     """Expand configs to the rectangular lane batch of one dispatch.
 
-    Returns ``(programs (K*nd_max, n_ops, 5), dyn with (K*nd_max,)
-    leaves, merged logical programs per config)``, where ``nd_max`` is
+    Returns the ``programs (K*nd_max, n_ops, 5)``, the ``dyn`` with
+    ``(K*nd_max,)`` leaves, the ``merged`` logical program per config
+    and the ``rebuilds`` (None unless a config fails), where ``nd_max`` is
     :func:`_nd_max` -- a config whose ``n_devices`` is below the widest
     member count in the set gets inert all-NOP pad lanes (configs with
     mixed array sizes still batch into ONE rectangular dispatch).  The
@@ -304,6 +349,13 @@ def build_fleet_batch(eng: ZoneEngine, configs: Sequence[FleetConfig],
     ``pad_quantum`` rounds the padded op axis up to a multiple (NOP
     rows are inert), so repeated same-size batches hit one compiled
     ``run_programs`` shape -- see :class:`Evaluator`.
+
+    A config with a ``failure`` stripes through
+    :func:`~repro.fleet.tenants.stripe_rebuild`: its rebuild rows carry
+    the tag ``N_TENANTS + 1``, and ``rebuilds`` (a
+    :class:`~repro.fleet.runner.Rebuilds`; hand it to ``run_fleet``)
+    holds each lane's rows issued before the failure and the survivor
+    reads each rebuilt chunk waits for.
 
     Every config is validated before anything is built.  Under a
     current profiler the op rows are timed as ``build.lanes`` and the
@@ -331,9 +383,21 @@ def build_fleet_batch(eng: ZoneEngine, configs: Sequence[FleetConfig],
                     f"engine's config (members: "
                     f"{[m.name for m in eng.members]}); build the engine "
                     f"over the search space's spec set")
+        if fc.failure is not None:
+            if not fc.parity:
+                raise ValueError(f"{fc.describe()}: a rebuild needs "
+                                 f"parity")
+            if not 0 <= fc.failure[0] < (fc.n_devices or n_devices):
+                raise ValueError(f"{fc.describe()}: no member "
+                                 f"{fc.failure[0]} to fail")
+            if not 0.0 <= fc.failure[1] <= 1.0:
+                raise ValueError(f"{fc.describe()}: the failure's share "
+                                 f"must be in [0, 1]")
     with span("build.lanes"):
         lane_programs: List[np.ndarray] = []
         merged_per_config: List[np.ndarray] = []
+        marks: List[int] = []
+        waits: List[np.ndarray] = []
         for fc in configs:
             nd = fc.n_devices or n_devices
             member_zp = seg_pages * fc.n_segments
@@ -344,13 +408,28 @@ def build_fleet_batch(eng: ZoneEngine, configs: Sequence[FleetConfig],
             if fidelity < 1.0:
                 merged = merged[: max(1, math.ceil(fidelity * len(merged)))]
             merged_per_config.append(merged)
-            lane_programs += stripe_program(
-                merged, n_devices=nd, chunk_pages=fc.chunk_pages,
-                parity=fc.parity, member_zone_pages=member_zp,
-                parity_tenant=N_TENANTS)
+            kw = dict(n_devices=nd, chunk_pages=fc.chunk_pages,
+                      parity=fc.parity, member_zone_pages=member_zp,
+                      parity_tenant=N_TENANTS)
+            if fc.failure is None:
+                lane_programs += stripe_program(merged, **kw)
+                marks += [-1] * nd
+            else:
+                rebuilt = stripe_rebuild(
+                    merged, member=fc.failure[0],
+                    at_row=int(fc.failure[1] * len(merged)), **kw)
+                # the replacement's and survivors' lanes in the batch
+                base = len(lane_programs)
+                row, src, src_row = rebuilt.waits.T
+                waits.append(np.stack([
+                    np.full_like(row, base + fc.failure[0]), row,
+                    base + src, src_row], axis=1))
+                lane_programs += rebuilt.lanes
+                marks += rebuilt.marks
             # inert pad lanes square up a mixed-member-count batch
             lane_programs += ([np.zeros((0, 5), dtype=np.int32)]
                               * (nd_max - nd))
+            marks += [-1] * (nd_max - nd)
         q = max(1, pad_quantum)
         n_ops = -(-max((len(p) for p in lane_programs), default=0)
                   // q) * q
@@ -367,7 +446,13 @@ def build_fleet_batch(eng: ZoneEngine, configs: Sequence[FleetConfig],
                      for d in range(nd)]
             dyns += [eng.dyn()] * (nd_max - nd)
         dyn = stack_dyn(dyns)
-    return programs, dyn, merged_per_config
+    rebuilds = None
+    if any(fc.failure is not None for fc in configs):
+        rebuilds = runner.Rebuilds(
+            tenant=N_TENANTS + 1, marks=np.asarray(marks),
+            group=np.arange(len(programs)) // max(nd_max, 1),
+            waits=np.concatenate(waits).reshape(-1, 4))
+    return FleetBatch(programs, dyn, merged_per_config, rebuilds)
 
 
 class Evaluator:
@@ -375,11 +460,12 @@ class Evaluator:
 
     Grid/random enumeration, and the evolutionary/successive-halving
     searcher in :mod:`repro.fleet.evolve`, all share this object: it
-    owns candidate expansion (:func:`build_fleet_batch`), the batched
+    owns candidate expansion (:func:`fleet_batch`), the batched
     execution + per-config rollups, the fixed scalar objective, and the
     budget ledger.  One :meth:`evaluate` call is one *dispatch*: one
-    batched ``run_programs`` + one batched timing invocation, whatever
-    the candidate count or fidelity.
+    batched ``run_programs`` + one batched timing invocation (three
+    where a config fails a member), whatever the candidate count or
+    fidelity.
 
     Budget ledger (cumulative, read by benchmarks/tests):
 
@@ -446,11 +532,13 @@ class Evaluator:
         if not configs:
             return []
         with span("evaluator.build", self.profiler):
-            programs, dyn, _ = build_fleet_batch(
+            programs, dyn, _, rebuilds = fleet_batch(
                 self.eng, configs, n_devices=self.n_devices,
                 fidelity=fidelity, pad_quantum=self.pad_quantum)
+        # the rebuild tag joins the timing only where a config fails:
+        # healthy batches keep their compiled timing shape
         res = runner.run_fleet(self.eng, programs, dyn=dyn,
-                               n_tenants=N_TENANTS,
+                               n_tenants=N_TENANTS, rebuilds=rebuilds,
                                profiler=self.profiler)
         if self.check_legal:
             with span("fleet.check", self.profiler):
@@ -483,6 +571,9 @@ class Evaluator:
                     "alloc_policy": fc.alloc_policy,
                     "fidelity": float(fidelity),
                 }
+                if fc.failure is not None:
+                    row["failed_member"] = float(fc.failure[0])
+                    row["fail_at"] = float(fc.failure[1])
                 row.update(runner.config_report(res, self.eng, lanes))
                 rows.append(row)
         return rows

@@ -25,7 +25,9 @@ model:
   profiler is not entered again, so rollups that call each other are
   counted once.  The fleet runner, the evaluator and the replay take a
   ``profiler=``; a check or rollup called inside any section finds the
-  current one;
+  current one.  ``count(name, n)`` adds to a program counter of the
+  same profiler (``Profiler.counters``), which ``emit_fleet_obs``
+  writes into its metrics registry;
 * :mod:`repro.obs.export`   -- Chrome/Perfetto ``trace_event`` JSON
   export (tenants -> tracks, ops -> duration events on the
   ``timing.simulate_fleet_ops`` clock) plus a counters/gauges metrics
@@ -45,7 +47,8 @@ from repro.obs.export import (MetricsRegistry, emit_fleet_obs,
                               fleet_trace_events, load_trace_schema,
                               validate_trace, write_trace)
 from repro.obs.profile import (COMPILE_LOG, CompileLog, Profiler,
-                               RecompileCounter, jit_cache_size, span)
+                               RecompileCounter, count, jit_cache_size,
+                               span)
 from repro.obs.recorder import (ObsConfig, TelemetryState,
                                 device_rollup, fleet_timelines,
                                 lane_timeline, telemetry_init,
@@ -57,7 +60,7 @@ __all__ = [
     "lane_timeline", "fleet_timelines", "tenant_timelines",
     "zone_timelines", "device_rollup",
     "COMPILE_LOG", "CompileLog", "Profiler", "RecompileCounter",
-    "jit_cache_size", "span",
+    "count", "jit_cache_size", "span",
     "MetricsRegistry", "fleet_trace_events", "write_trace",
     "validate_trace", "load_trace_schema", "emit_fleet_obs",
 ]

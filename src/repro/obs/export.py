@@ -239,8 +239,9 @@ def emit_fleet_obs(res, eng, *, obs, out_prefix,
     * ``<out_prefix>_trace.json`` -- the Perfetto trace (validated
       against the checked-in schema before returning);
     * ``<out_prefix>_obs.json``   -- telemetry timelines (per lane +
-      per tenant + pooled), the metrics registry, and (when given) the
-      profiler sections and recompile-counter readings.
+      per tenant + pooled), the metrics registry (with the profiler's
+      program counters, when given), and (when given) the profiler
+      sections and recompile-counter readings.
 
     ``res`` must come from ``run_fleet(..., obs=obs)`` so it carries
     the telemetry stack.  Returns ``{"trace": path, "obs": path,
@@ -258,6 +259,8 @@ def emit_fleet_obs(res, eng, *, obs, out_prefix,
     lanes = recorder.fleet_timelines(obs, res.telemetry)
     with span("fleet.rollup", profiler):
         metrics = fleet_metrics(res, eng).as_dict()
+    if profiler is not None:
+        metrics["counters"].update(profiler.counters)
     obs_obj = {
         "schema_version": 1,
         "meta": dict(meta or {}),

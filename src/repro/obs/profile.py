@@ -19,7 +19,8 @@ Three instruments, all cheap enough to leave on:
   recompile leak the ROADMAP's interference regression turned out to
   be (see ``workloads.interference_sweep_engine``).
 * :func:`span` -- the one call library code uses to mark its host
-  work.  A :class:`Profiler` section makes its profiler the *current*
+  work (and :func:`count`, the one it adds to a program counter
+  with).  A :class:`Profiler` section makes its profiler the *current*
   one for the section's duration; ``span(name)`` opens a section of
   the profiler it is given, else of the current one, else does nothing
   (a shared no-op context: no clock read, no allocation).  So a
@@ -108,6 +109,8 @@ class Profiler:
 
     def __init__(self, compile_log: Optional[CompileLog] = None) -> None:
         self.sections: Dict[str, Dict[str, float]] = {}
+        #: program counters, by name, added to by :func:`count`
+        self.counters: Dict[str, float] = {}
         self._log = compile_log if compile_log is not None else COMPILE_LOG
         self._open: Set[str] = set()
 
@@ -164,6 +167,14 @@ def span(name: str, profiler: Optional[Profiler] = None):
     if prof is None or name in prof._open:
         return _NOOP
     return prof.section(name)
+
+
+def count(name: str, inc: float = 1.0) -> None:
+    """Add ``inc`` to the current profiler's counter ``name`` (named as
+    spans are, ``<layer>.<what>``); nothing without a profiler."""
+    prof = _CURRENT.get()
+    if prof is not None:
+        prof.counters[name] = prof.counters.get(name, 0.0) + float(inc)
 
 
 def jit_cache_size(fn) -> int:
