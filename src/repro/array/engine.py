@@ -7,7 +7,7 @@ interpreting it: every zone command is lowered host-side into encoded
 per-member op rows (using the same module-level stripe math
 ``fleet/tenants.py`` shares with the object array), and the whole
 member fleet then executes in ONE batched ``run_programs`` dispatch --
-one ``lax.scan`` per member lane, all lanes in one ``lax.map``.
+one ``lax.scan`` over each member lane's rows.
 
 The host side keeps only the superzone mirror (``SuperZoneInfo`` per
 zone, the same metadata the object array keeps): enough to validate

@@ -193,6 +193,8 @@ class OpTrace(NamedTuple):
     erase_delta: jax.Array  # () i32
     elems: jax.Array       # (n_slots,) i32  zone slot row *after* the op
     cols: jax.Array        # (parallelism,) i32 zone column -> LUN
+    opens: jax.Array       # () bool  an ALLOC or WRITE on an EMPTY zone:
+                           #   the op ran the allocator
 
 
 class DynConfig(NamedTuple):
@@ -595,6 +597,83 @@ def _rr_mask(cfg: EngineConfig, dyn: DynConfig, start: jax.Array
     return jnp.zeros(cfg.n_groups, bool).at[idx].set(True)
 
 
+def _in_lane_groups(dense):
+    """Run the decorated per-lane ``fn(cfg, *args)`` as it is for one
+    lane, and ``dense`` (same signature, same bits) in its place when a
+    lane group batches it.  ``dense`` uses no sort, gather or scatter:
+    batched, those run as kernels whose cost grows with every lane
+    (on one TPU v5e a step of 32 lanes spent 55% of its time in the
+    sorts of ``top_k`` and 20% in its gathers), while min-reductions
+    and selects over a row batch into dense kernels."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(cfg, *args):
+            args = jax.tree_util.tree_map(jnp.asarray, args)
+
+            @jax.custom_batching.custom_vmap
+            def one(*args):
+                return fn(cfg, *args)
+
+            @one.def_vmap
+            def _(axis_size, in_batched, *args):
+                axes = jax.tree_util.tree_map(lambda b: 0 if b else None,
+                                              tuple(in_batched))
+                out = jax.vmap(functools.partial(dense, cfg), in_axes=axes,
+                               axis_size=axis_size)(*args)
+                return out, jax.tree_util.tree_map(lambda _: True, out)
+
+            return one(*args)
+        return call
+    return wrap
+
+
+def _smallest_distinct(key: jax.Array, k: int) -> jax.Array:
+    """The ``k`` smallest entries of each row of ``key`` (distinct,
+    non-negative int32 within a row), ascending, by ``k``
+    min-reductions: each the least entry past the one before."""
+    big = jnp.iinfo(jnp.int32).max
+
+    def nxt(last, _):
+        last = jnp.min(jnp.where(key > last[..., None], key, big), axis=-1)
+        return last, last
+
+    _, out = jax.lax.scan(nxt, jnp.full(key.shape[:-1], -1, key.dtype),
+                          None, length=k)
+    return jnp.moveaxis(out, 0, -1)
+
+
+def _take_lowest_dense(cfg: EngineConfig, dyn: DynConfig, w2, a2, eligible,
+                       by_wear, take_eff):
+    """:func:`_take_lowest` with no sort or gather.  Non-free entries
+    get the distinct keys ``_BIG + col``, which order them as ``top_k``
+    orders its ties (lower column first), so the ``take`` smallest
+    distinct keys give ``top_k``'s selection; the column, and whether
+    the entry is free, come back out of the key."""
+    pg, k = cfg.per_group, cfg.take
+    free = (a2 == AVAIL_FREE) | (a2 == AVAIL_INVALID)
+    col = jnp.arange(pg, dtype=jnp.int32)[None, :]
+    free = free & eligible[:, None] & (col < dyn.per_group)
+    key = jnp.where(free, jnp.where(by_wear, w2 * pg + col, col),
+                    _BIG + col)
+    v = _smallest_distinct(key, k)
+    real = v < _BIG
+    cols = jnp.where(real, jnp.where(by_wear, v % pg, v), v - _BIG)
+    rank = jnp.arange(k, dtype=jnp.int32)[None, :]
+    got_all = jnp.any(real & (rank == take_eff - 1), axis=1)
+    feasible = jnp.all(got_all | ~eligible)
+    wsel = jnp.sum(jnp.where(col[:, None, :] == cols[:, :, None],
+                             w2[:, None, :], 0), axis=2)
+    sel_key = jnp.where(real & (rank < take_eff), wsel * pg + cols, _BIG)
+    # the stable argsort of sel_key, as each entry's destination rank
+    a, b = sel_key[:, :, None], sel_key[:, None, :]
+    before = (b < a) | ((b == a) & (rank[None, :, :] < rank[:, :, None]))
+    dest = jnp.sum(before, axis=2)
+    cols = jnp.sum(jnp.where(dest[:, None, :] == rank[:, :, None],
+                             cols[:, None, :], 0), axis=2)
+    return cols.astype(jnp.int32), feasible
+
+
+@_in_lane_groups(_take_lowest_dense)
 def _take_lowest(cfg: EngineConfig, dyn: DynConfig, w2, a2, eligible,
                  by_wear, take_eff):
     """Per-eligible-group ``take`` lowest-(wear, col) available elements.
@@ -647,6 +726,25 @@ def _take_lowest(cfg: EngineConfig, dyn: DynConfig, w2, a2, eligible,
     return cols, feasible
 
 
+def _smallest_wear_dense(cfg: EngineConfig, w2, ok):
+    """:func:`_smallest_wear` with no sort: the wear of an entry is
+    ``key // per_group`` of its distinct key ``wear * per_group + col``
+    (``_BIG + col`` where not ``ok``, read back as inf)."""
+    col = jnp.arange(cfg.per_group, dtype=jnp.int32)[None, :]
+    v = _smallest_distinct(
+        jnp.where(ok, w2 * cfg.per_group + col, _BIG + col), cfg.take)
+    return jnp.where(v < _BIG, (v // cfg.per_group).astype(jnp.float32),
+                     jnp.inf)
+
+
+@_in_lane_groups(_smallest_wear_dense)
+def _smallest_wear(cfg: EngineConfig, w2, ok) -> jax.Array:
+    """The ``take`` smallest wears of each group row among ``ok``
+    entries, ascending, as float32 (inf past the ``ok`` ones)."""
+    keyed = jnp.where(ok, w2.astype(jnp.float32), jnp.inf)
+    return -jax.lax.top_k(-keyed, cfg.take)[0]
+
+
 def _cheapest_groups(cfg: EngineConfig, dyn: DynConfig, w2, a2, take_eff
                      ) -> jax.Array:
     ng = dyn.n_elements // dyn.per_group
@@ -654,8 +752,7 @@ def _cheapest_groups(cfg: EngineConfig, dyn: DynConfig, w2, a2, take_eff
     col = jnp.arange(cfg.per_group, dtype=jnp.int32)[None, :]
     ok = (a2 == AVAIL_FREE) | (a2 == AVAIL_INVALID)
     ok = ok & (grow < ng) & (col < dyn.per_group)  # union-grid padding
-    keyed = jnp.where(ok, w2.astype(jnp.float32), jnp.inf)
-    part = -jax.lax.top_k(-keyed, cfg.take)[0]  # take smallest per row
+    part = _smallest_wear(cfg, w2, ok)
     rank = jnp.arange(cfg.take, dtype=jnp.int32)[None, :]
     cost = jnp.where(rank < take_eff, part, 0.0).sum(axis=1)
     order = jnp.argsort(cost, stable=True)[: cfg.zone_groups]
@@ -685,6 +782,40 @@ def _wear_bounded_avail(cfg: EngineConfig, dyn: DynConfig, w2, a2
 def _where_state(pred, new: DeviceState, old: DeviceState) -> DeviceState:
     return jax.tree_util.tree_map(
         lambda a, b: jnp.where(pred, a, b), new, old)
+
+
+def _lane_cond(pred, true_fn, false_fn, *operands):
+    """``lax.cond(pred, true_fn, false_fn, *operands)`` for one lane,
+    which a lane group (``vmap``) runs at group level.
+
+    Batched, ``lax.cond`` runs both branches for every lane and
+    selects.  Here the group runs ``false_fn`` alone when no lane's
+    ``pred`` holds, and both with that per-lane select otherwise: the
+    same bits, and ``true_fn`` only in the steps some lane of the group
+    takes it.  The branches take ``operands`` alone (they close over no
+    traced value)."""
+    @jax.custom_batching.custom_vmap
+    def cond(pred, *ops):
+        return jax.lax.cond(pred, true_fn, false_fn, *ops)
+
+    @cond.def_vmap
+    def _(axis_size, in_batched, pred, *ops):
+        axes = jax.tree_util.tree_map(lambda b: 0 if b else None,
+                                      tuple(in_batched[1:]))
+        vt = jax.vmap(true_fn, in_axes=axes, axis_size=axis_size)
+        vf = jax.vmap(false_fn, in_axes=axes, axis_size=axis_size)
+        if not in_batched[0]:
+            pred = jnp.broadcast_to(pred, (axis_size,))
+
+        out_f = vf(*ops)
+        out_t = jax.lax.cond(jnp.any(pred), vt, lambda *_: out_f, *ops)
+        out = jax.tree_util.tree_map(
+            lambda a, b: jnp.where(
+                pred.reshape(pred.shape + (1,) * (a.ndim - 1)), a, b),
+            out_t, out_f)
+        return out, jax.tree_util.tree_map(lambda _: True, out)
+
+    return cond(pred, *operands)
 
 
 # ----------------------------------------------------------------------- #
@@ -736,8 +867,9 @@ def _alloc(cfg: EngineConfig, dyn: DynConfig, state: DeviceState,
             n_slots_eff // jnp.maximum(dyn.slot_stride, 1),
             1, dyn.take).astype(jnp.int32)
 
-        def traditional(_):
-            elig1 = _rr_mask(cfg, dyn, state.rr_next)
+        # the branches take their traced inputs as operands (_lane_cond)
+        def traditional(dyn, w2, a2, rr, take_eff, hint):
+            elig1 = _rr_mask(cfg, dyn, rr)
             cols1, f1 = _take_lowest(cfg, dyn, w2, a2, elig1,
                                      dyn.wear_aware, take_eff)
 
@@ -745,21 +877,24 @@ def _alloc(cfg: EngineConfig, dyn: DynConfig, state: DeviceState,
             # instead (the legacy fallback always uses the wear-aware
             # selection); lazily computed -- the common path pays for
             # one top_k only
-            def fallback(_):
+            def fallback(dyn, w2, a2, take_eff, cols1, elig1):
                 elig2 = _cheapest_groups(cfg, dyn, w2, a2, take_eff)
                 cols2, f2 = _take_lowest(cfg, dyn, w2, a2, elig2, True,
                                          take_eff)
                 return cols2, f2, elig2
 
-            cols1, f2, elig1 = jax.lax.cond(
-                f1, lambda _: (cols1, f1, elig1), fallback, None)
+            cols1, f2, elig1 = _lane_cond(
+                ~f1, fallback,
+                lambda dyn, w2, a2, take_eff, cols1, elig1: (
+                    cols1, jnp.asarray(True), elig1),
+                dyn, w2, a2, take_eff, cols1, elig1)
             # legacy advances the window even when the allocation then
             # fails
             ng = dyn.n_elements // dyn.per_group
-            rr = (state.rr_next + dyn.zone_groups) % ng
-            return cols1, f1 | f2, elig1, rr, dyn.take
+            return (cols1, f1 | f2, elig1, (rr + dyn.zone_groups) % ng,
+                    dyn.take)
 
-        def silent(_):
+        def silent(dyn, w2, a2, rr, take_eff, hint):
             # on-the-fly commitment: only the ranks the size hint needs
             # (>= 1, keeping the parallelism floor of one element per
             # winning group), from the cheapest wear-bounded groups;
@@ -772,10 +907,11 @@ def _alloc(cfg: EngineConfig, dyn: DynConfig, state: DeviceState,
             elig_s = _cheapest_groups(cfg, dyn, w2, a2b, take_s)
             cols_s, f_s = _take_lowest(cfg, dyn, w2, a2b, elig_s, True,
                                        take_s)
-            return cols_s, f_s, elig_s, state.rr_next, take_s
+            return cols_s, f_s, elig_s, rr, take_s
 
-        cols, feasible, elig, rr_next, rank_lim = jax.lax.cond(
-            dyn.alloc_policy == POLICY_SILENT, silent, traditional, None)
+        cols, feasible, elig, rr_next, rank_lim = _lane_cond(
+            dyn.alloc_policy == POLICY_SILENT, silent, traditional,
+            dyn, w2, a2, state.rr_next, take_eff, hint)
         # every eligible group contributes exactly ``take`` elements, so
         # the winning groups are the eligible window itself (ascending)
         win = jnp.nonzero(elig, size=cfg.zone_groups,
@@ -885,7 +1021,7 @@ def _grow_silent(cfg: EngineConfig, dyn: DynConfig, state: DeviceState,
     grow = (pred & (dyn.alloc_policy == POLICY_SILENT)
             & (need > have))
 
-    def grow_fn(s):
+    def grow_fn(s, dyn, zone, need, have, n_slots_eff):
         n = cfg.n_elements
         pg = cfg.per_group
         w2 = s.elem_wear[:n].reshape(cfg.n_groups, pg)
@@ -930,25 +1066,17 @@ def _grow_silent(cfg: EngineConfig, dyn: DynConfig, state: DeviceState,
         )
         return _where_state(fg, new, s), fg
 
-    return jax.lax.cond(
-        grow, grow_fn, lambda s: (s, jnp.asarray(True)), state)
+    return _lane_cond(
+        grow, grow_fn, lambda s, *_: (s, jnp.asarray(True)),
+        state, dyn, zone, need, have, n_slots_eff)
 
 
 def _write(cfg: EngineConfig, dyn: DynConfig, state: DeviceState,
-           zone, n_pages, host) -> Tuple[DeviceState, jax.Array]:
-    zst0 = state.zone_state[zone]
-    state, aok = jax.lax.cond(
-        zst0 == ZONE_EMPTY,
-        lambda s: _alloc(cfg, dyn, s, zone, n_pages),
-        lambda s: (s, jnp.asarray(True)),
-        state)
-    wp0 = state.zone_wp[zone]
-    wp1 = wp0 + n_pages
-    fits = wp1 <= dyn.zone_pages
-    state, gok = _grow_silent(cfg, dyn, state, zone, wp1,
-                              (zst0 != ZONE_FULL) & aok & fits)
-    ok = (zst0 != ZONE_FULL) & aok & fits & gok
-
+           zone, n_pages, host, ok) -> Tuple[DeviceState, jax.Array]:
+    """WRITE's effects on a zone that holds its elements (an EMPTY zone
+    was allocated and a silent one grown before, in
+    :func:`_apply_op_impl`); ``ok`` is the op's verdict."""
+    wp1 = state.zone_wp[zone] + n_pages
     written = _written_per_slot(cfg, dyn, wp1).astype(jnp.int32)
     elems = state.zone_elems[zone]
     valid = elems >= 0
@@ -1039,26 +1167,33 @@ def _apply_op_impl(cfg: EngineConfig, dyn: DynConfig, state: DeviceState,
     n_pages = row[2]
     host = (row[3] & F_HOST) == F_HOST
 
+    # an ALLOC, or a WRITE, on an EMPTY zone first claims its elements
+    # (row[2] rides along as the silent policy's size hint); then a
+    # WRITE a silent zone's committed ranks cannot hold grows the zone.
+    # Both run ahead of the per-op switch, so that a lane group (see
+    # _lane_cond) pays for them only in the steps some lane needs them.
+    zst0 = state.zone_state[zone]
+    opens = ((op == OP_ALLOC) | (op == OP_WRITE)) & (zst0 == ZONE_EMPTY)
+    s1, aok = _lane_cond(
+        opens, lambda s, d, z, h: _alloc(cfg, d, s, z, h),
+        lambda s, *_: (s, jnp.asarray(True)), state, dyn, zone, n_pages)
+    wp1 = s1.zone_wp[zone] + n_pages
+    fits = (zst0 != ZONE_FULL) & aok & (wp1 <= dyn.zone_pages)
+    s1, gok = _grow_silent(cfg, dyn, s1, zone, wp1,
+                           (op == OP_WRITE) & fits)
+
     def nop(s):
         return s, jnp.asarray(True)
-
-    def alloc_branch(s):
-        zst0 = s.zone_state[zone]
-        # row[2] rides along as the silent policy's size hint
-        s2, ok = _alloc(cfg, dyn, s, zone, n_pages)
-        # no-op (and fine) when the zone is already mapped
-        return (_where_state(zst0 == ZONE_EMPTY, s2, s),
-                jnp.where(zst0 == ZONE_EMPTY, ok, True))
 
     state2, ok = jax.lax.switch(
         jnp.clip(op, 0, OP_READ),
         [nop,
-         alloc_branch,
-         lambda s: _write(cfg, dyn, s, zone, n_pages, host),
+         lambda s: (s, aok),   # ALLOC: a mapped zone is a fine no-op
+         lambda s: _write(cfg, dyn, s, zone, n_pages, host, fits & gok),
          lambda s: _finish(cfg, dyn, s, zone),
          lambda s: _reset(cfg, s, zone),
          nop],  # OP_READ: reads never change device state
-        state)
+        s1)
     trace = OpTrace(
         op=op, zone=zone, ok=ok,
         wp_before=state.zone_wp[zone],
@@ -1068,6 +1203,7 @@ def _apply_op_impl(cfg: EngineConfig, dyn: DynConfig, state: DeviceState,
         erase_delta=state2.block_erases - state.block_erases,
         elems=state2.zone_elems[zone],
         cols=state2.zone_cols[zone],
+        opens=opens,
     )
     return state2, trace
 
@@ -1127,6 +1263,77 @@ def run_program(cfg: EngineConfig, state: DeviceState, program: jax.Array,
     return _scan_program(cfg, dyn, state, program, obs)
 
 
+#: lane-group width rule of :func:`run_programs` on the TPU (see there)
+LANE_GROUP_MIN_LANES = 64
+LANE_GROUP_MAX_WIDTH = 384
+
+
+def lane_group_width(n_lanes: int, platform: str) -> int:
+    """Lanes one :func:`run_programs` step advances together on
+    ``platform`` (a JAX platform name): 1 (lanes one after another)
+    off the TPU and below ``LANE_GROUP_MIN_LANES`` lanes, else
+    ``min(n_lanes, LANE_GROUP_MAX_WIDTH)``."""
+    if platform != "tpu" or n_lanes < LANE_GROUP_MIN_LANES:
+        return 1
+    return min(n_lanes, LANE_GROUP_MAX_WIDTH)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def group_alloc_steps(opens: jax.Array, width: int) -> jax.Array:
+    """``(groups,)`` i32: the steps of each lane group of ``width`` in
+    which some lane ran the allocator (``opens``: a dispatch's
+    ``(n_lanes, n_ops)`` ``OpTrace.opens``)."""
+    opens = jnp.pad(opens, ((0, -opens.shape[0] % width), (0, 0)))
+    return jnp.sum(jnp.any(opens.reshape(-1, width, opens.shape[1]),
+                           axis=1), axis=1, dtype=jnp.int32)
+
+
+def _lane_dyn(cfg: EngineConfig, dyn: Optional[DynConfig],
+              n_lanes: int) -> DynConfig:
+    """``dyn`` with ``(n_lanes,)`` leaves (``cfg``'s own when None)."""
+    if dyn is not None:
+        return dyn
+    return jax.tree_util.tree_map(
+        lambda x: jnp.broadcast_to(jnp.asarray(x), (n_lanes,)),
+        make_dyn(cfg))
+
+
+def _run_lanes(cfg: EngineConfig, state: DeviceState, programs: jax.Array,
+               dyn: DynConfig, obs):
+    """Lanes one after another: a ``lax.map`` of the per-lane scan."""
+    return jax.lax.map(
+        lambda pd: _scan_program(cfg, pd[1], state, pd[0], obs),
+        (programs, dyn))
+
+
+def _run_lane_groups(cfg: EngineConfig, state: DeviceState,
+                     programs: jax.Array, dyn: DynConfig, obs, width: int):
+    """Lanes in groups of ``width``: one scan over the op rows applies
+    row ``t`` of every lane of a group in the same step, on lane-major
+    state; the groups run one after another.  A lane count ``width``
+    does not divide is filled up with NOP lanes (under lane 0's
+    ``DynConfig``), whose outputs are dropped.  Each lane gets its own
+    copy of the initial state, so the scan's carry is batched from the
+    start and the step is batched (and traced) once."""
+    n = programs.shape[0]
+    fill = -n % width
+    if fill:
+        programs = jnp.concatenate(
+            [programs, jnp.zeros((fill,) + programs.shape[1:],
+                                 programs.dtype)])
+        dyn = jax.tree_util.tree_map(
+            lambda x: jnp.concatenate(
+                [x, jnp.broadcast_to(x[:1], (fill,) + x.shape[1:])]), dyn)
+    states = jax.tree_util.tree_map(
+        lambda x: jnp.broadcast_to(x, (n + fill,) + x.shape), state)
+    out = jax.lax.map(
+        lambda pds: _scan_program(cfg, pds[1], pds[2], pds[0], obs),
+        (programs, dyn, states), batch_size=width)
+    if fill:
+        out = jax.tree_util.tree_map(lambda x: x[:n], out)
+    return out
+
+
 @functools.partial(jax.jit, static_argnums=(0,),
                    static_argnames=("obs",))
 def run_programs(cfg: EngineConfig, state: DeviceState, programs: jax.Array,
@@ -1143,17 +1350,28 @@ def run_programs(cfg: EngineConfig, state: DeviceState, programs: jax.Array,
     telemetry stacks (``(n_programs, n_buckets, ...)`` leaves): the
     return becomes ``(states, traces, telemetry)``.
 
-    Uses ``lax.map`` rather than ``jax.vmap``: the transitions are
-    scatter/gather-heavy and batching them materializes every branch of
-    the per-op ``switch`` for every lane, which is several times slower
-    on CPU than mapping the already-tight single-device scan."""
-    if dyn is None:
-        return jax.lax.map(
-            lambda p: _scan_program(cfg, make_dyn(cfg), state, p, obs),
-            programs)
-    return jax.lax.map(
-        lambda pd: _scan_program(cfg, pd[1], state, pd[0], obs),
-        (programs, dyn))
+    How the lanes run depends on the platform and the lane count
+    (:func:`lane_group_width`, resolved while tracing from the default
+    backend and the static lane count).  On the CPU they run one after
+    another, a ``lax.map`` of the single-lane scan: batching makes every
+    lane run every branch of the per-op ``switch``, 27x slower there at
+    128 lanes.  On the TPU, from 64 lanes, they run in lane
+    groups of up to 384 (:func:`_run_lane_groups`), where the allocator
+    runs only in the steps some lane of a group allocates
+    (:func:`_lane_cond`) and in forms without sorts or gathers
+    (:func:`_in_lane_groups`).  The rule is one TPU v5e's: the
+    grid's 384 lanes x 832 rows took 6.9 s one lane after another and
+    3.9 s in one group (4.1 s in groups of 96, 4.6 s of 32), while a
+    group step costs at least ~0.45 ms, so kvbench's 6 lanes x 960
+    rows took 0.15 s one after another and 0.43 s in one group.  Below
+    64 lanes a group saves a call little against what its larger
+    program adds to tracing and loading, once per scan shape."""
+    n = programs.shape[0]
+    dyn = _lane_dyn(cfg, dyn, n)
+    width = lane_group_width(n, jax.default_backend())
+    if width == 1:
+        return _run_lanes(cfg, state, programs, dyn, obs)
+    return _run_lane_groups(cfg, state, programs, dyn, obs, width)
 
 
 # ----------------------------------------------------------------------- #

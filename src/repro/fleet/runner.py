@@ -5,10 +5,10 @@ A fleet *lane* is one (config, member-device) pair: a width-5 op program
 :class:`repro.core.engine.DynConfig` selecting the member's effective
 zone geometry / allocator on the shared padded static
 :class:`~repro.core.engine.EngineConfig`.  :func:`run_fleet` stacks all
-lanes and executes them through ONE ``run_programs`` dispatch (a
-``lax.map`` of scan-compiled programs), then scores latency with ONE
-:func:`repro.core.timing.simulate_fleet_ops` dispatch -- no per-config
-or per-device Python loops on the hot path.
+lanes and executes them through ONE ``run_programs`` dispatch (scans
+over the lanes' rows, in lane groups on the TPU), then scores latency
+with ONE :func:`repro.core.timing.simulate_fleet_ops` dispatch -- no
+per-config or per-device Python loops on the hot path.
 
 Metric units: page counters count flash pages, ``erase_delta`` counts
 erase-block erasures, times are seconds.
@@ -27,7 +27,7 @@ from repro.core import timing
 from repro.core.elements import union_grid_mask
 from repro.core.engine import DeviceState, DynConfig, ZoneEngine
 from repro.fleet.tenants import TENANT_COL
-from repro.obs.profile import span
+from repro.obs.profile import count, span
 
 
 class Rebuilds(NamedTuple):
@@ -213,9 +213,16 @@ def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
                          f"{programs.shape}")
     if parity_tenant is None:
         parity_tenant = n_tenants
+    # the lane groups run_programs steps through (engine.lane_group_width)
+    n_lanes, n_rows = programs.shape[:2]
+    width = zengine.lane_group_width(n_lanes, jax.default_backend())
+    groups = -(-n_lanes // width)
     with span("fleet.engine", profiler) as timed:
+        count("engine.groups", groups)
+        count("engine.lane_steps", groups * width * n_rows)
         out = eng.run_batch(eng.init_state(), programs, dyn, obs=obs)
         states, trace = out[0], out[1]
+        alloc_steps = zengine.group_alloc_steps(trace.opens, width)
         telemetry = out[2] if obs is not None else None
         elem_mask = None
         if dyn is not None:
@@ -230,10 +237,10 @@ def run_fleet(eng: ZoneEngine, programs: np.ndarray, *,
             jax.block_until_ready(states)
 
     with span("fleet.timing", profiler) as timed:
-        cols = np.asarray(trace.cols)
-        wp_b = np.asarray(trace.wp_before)
-        wp_a = np.asarray(trace.wp_after)
-        dummy = np.asarray(trace.dummy_delta)
+        cols, wp_b, wp_a, dummy, alloc_steps = jax.device_get((
+            trace.cols, trace.wp_before, trace.wp_after, trace.dummy_delta,
+            alloc_steps))
+        count("engine.alloc_steps", int(alloc_steps.sum()))
         op = programs[:, :, 0]
         # pages the op physically moved: write advance, FINISH padding
         # (RESET rewinds wp without moving pages -> clip), READ

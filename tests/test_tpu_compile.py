@@ -8,7 +8,8 @@ compiled at the paper's zn540 widths, with few lanes so each compile
 stays a few seconds:
 
 * ``run_programs`` on the superblock/block/vchunk2 union engine with a
-  per-lane ``DynConfig`` stack (both allocation policies);
+  per-lane ``DynConfig`` stack (both allocation policies), and the
+  grid cell's 384-lane dispatch in the lane groups the TPU runs;
 * ``simulate_fleet_ops`` over the same lanes;
 * the ``zns_alloc`` Pallas kernel at zn540 and custom16 shapes for the
   three specs (custom16's BLOCK spec splits its 16 groups into blocks).
@@ -76,6 +77,32 @@ def test_run_programs_compiles_for_v5e(one_chip):
     assert trace.ok.shape == (LANES, OPS)
     assert trace.elems.shape == (LANES, OPS, eng.cfg.n_slots)
     assert compiled.memory_analysis().output_size_in_bytes > 0
+
+
+def test_grid_dispatch_compiles_in_lane_groups_for_v5e(one_chip):
+    """The grid cell's dispatch, 384 lanes x 832 rows, in the lane-group
+    form the TPU rule picks at 384 lanes, fits one chip's scratch
+    memory with room to spare."""
+    flash, zone = zn540()
+    eng = E.ZoneEngine(flash, zone, SPECS, max_active=14)
+    lanes, ops = 384, 832
+    width = E.lane_group_width(lanes, "tpu")
+    assert width > 1
+    state = _on(one_chip, jax.eval_shape(lambda: E.init_state(eng.cfg)))
+    dyn = _on(one_chip, jax.eval_shape(lambda: E.stack_dyn([
+        eng.dyn(spec=SPECS[k % len(SPECS)],
+                alloc_policy=("traditional", "silent")[k % 2])
+        for k in range(lanes)])))
+    programs = jax.ShapeDtypeStruct((lanes, ops, 5), jnp.int32,
+                                    sharding=one_chip)
+    groups = jax.jit(E._run_lane_groups, static_argnums=(0, 4, 5))
+    compiled = groups.lower(eng.cfg, state, programs, dyn, None,
+                            width).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    _, trace = jax.eval_shape(
+        lambda s, p, d: E._run_lane_groups(eng.cfg, s, p, d, None, width),
+        state, programs, dyn)
+    assert trace.ok.shape == (lanes, ops)
 
 
 def test_simulate_fleet_ops_compiles_for_v5e(one_chip):
