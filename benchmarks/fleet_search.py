@@ -41,9 +41,9 @@ events) -- plus ``<prefix>_obs.json`` (telemetry timelines, metrics,
 dispatch profile, recompile table; render it with
 ``tools/obs_report.py``).
 
-The batched-vs-legacy speedup and the evolve-vs-random
-dispatches-to-target comparison live in ``tools/bench.py`` (artifact
-``BENCH_fleet.json``), not here.
+The evolve-vs-random dispatches-to-target comparison is
+:func:`repro.fleet.evolve.evolve_vs_random`, asserted in
+``tests/test_evolve.py``.
 """
 
 from __future__ import annotations

@@ -4,28 +4,8 @@ from __future__ import annotations
 
 import sys
 
-import numpy as np
-
 from benchmarks import ckpt_zns, paper_figures, roofline_report
 from benchmarks.common import Bench
-from repro.core import workloads, zn540
-from repro.core.elements import SUPERBLOCK
-
-
-def engine_batched_drivers() -> dict:
-    """The fig4a/fig4b workloads through the scan-compiled engine: the
-    dlwa occupancy sweep as one batched scan and interference as fused
-    finish+host-write programs, with the measured speedup over the
-    legacy per-op loop (tools/bench.py archives the same numbers)."""
-    rep = workloads.engine_vs_legacy_speedup(
-        occupancies=tuple(float(o) for o in np.linspace(0.05, 0.95, 16)),
-        n_zones=8, concurrencies=(1, 2, 4, 7), repeats=2)
-    flash, zone = zn540()
-    eng = workloads.make_engine(flash, zone, SUPERBLOCK, max_active=28)
-    sweep = workloads.dlwa_sweep_engine(
-        eng, (0.1, 0.3, 0.5, 0.7, 0.9), n_zones=4)
-    rep["dlwa_at_10pct"] = sweep[0]["dlwa"]
-    return rep
 
 
 def main() -> None:
@@ -61,9 +41,6 @@ def main() -> None:
              ("fixed_us", "superblock_us", "block_us"))
     b.timeit("ckpt_zns_all_archs", ckpt_zns.run_all,
              ("mean_dlwa_reduction", "worst_baseline_dlwa"))
-    b.timeit("engine_batched_drivers", engine_batched_drivers,
-             ("dlwa_speedup", "interference_speedup",
-              "dlwa_engine_ops_s", "dlwa_legacy_ops_s", "dlwa_at_10pct"))
 
     try:
         s = roofline_report.summary()

@@ -14,8 +14,7 @@ the object ``ZNSArray`` kept as the bit-exactness oracle.
 ``repro.array.storm`` runs batched rebuild storms on top of it.
 """
 
-from repro.array.engine import (ArrayEngine, ArrayResult,
-                                array_vs_legacy_speedup, apply_commands,
+from repro.array.engine import (ArrayEngine, ArrayResult, apply_commands,
                                 fill_commands, merge_rebuild,
                                 plan_rebuild, run_array_batch,
                                 run_array_timing)
@@ -26,7 +25,7 @@ from repro.array.storm import StormScenario, rebuild_storm
 
 __all__ = ["ArrayEngine", "ArrayGeometry", "ArrayResult", "StormScenario",
            "SuperZoneInfo", "TaggedTrace", "ZNSArray", "apply_commands",
-           "array_vs_legacy_speedup", "data_device_of", "fill_commands",
+           "data_device_of", "fill_commands",
            "locate_page", "member_chunk_pages", "merge_rebuild",
            "parity_device_of", "plan_rebuild", "rebuild_storm",
            "run_array_batch", "run_array_timing"]

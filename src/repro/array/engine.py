@@ -24,9 +24,7 @@ shared initial state.
 The object ``ZNSArray`` stays as the bit-exactness oracle (the
 ``LegacyZNSDevice`` pattern): :meth:`ArrayEngine.report` and
 :meth:`ArrayEngine.device_reports` reproduce its rollups exactly
-(differential-tested in ``tests/test_array_engine.py``), and
-:func:`array_vs_legacy_speedup` is the comparator ``tools/bench.py``
-gates in ``BENCH_fleet.json``.
+(differential-tested in ``tests/test_array_engine.py``).
 
 Batched sweeps: :func:`run_array_batch` stacks K arrays (mixed member
 counts, chunk sizes, parity settings, and -- on a union-config engine
@@ -38,7 +36,6 @@ on top of it.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -50,7 +47,7 @@ from repro.core import engine as zengine
 from repro.core import timing
 from repro.core.alloc_exact import AVAIL_INVALID
 from repro.core.device import ZoneState
-from repro.core.elements import SUPERBLOCK, ElementSpec
+from repro.core.elements import ElementSpec
 from repro.core.engine import DeviceState, ZoneEngine, stack_dyn
 
 #: column index of the tenant tag in a width-5 op row (same convention
@@ -780,7 +777,7 @@ def run_array_timing(flash, programs: np.ndarray, cols: np.ndarray,
 
 
 # --------------------------------------------------------------------- #
-# differential replay + the bench comparator
+# differential replay
 # --------------------------------------------------------------------- #
 #: command tuples: ("write", zone, n_pages, host) / ("finish", zone) /
 #: ("reset", zone) / ("read", zone, offsets) / ("fail", idx) /
@@ -790,8 +787,8 @@ Command = tuple
 
 def apply_commands(backend, commands: Sequence[Command]):
     """Drive an :class:`ArrayEngine` or an object :class:`ZNSArray`
-    through one logical command list -- the shared differential-test /
-    comparator driver (both surfaces take the same verbs)."""
+    through one logical command list -- the shared differential-test
+    driver (both surfaces take the same verbs)."""
     for cmd in commands:
         verb = cmd[0]
         if verb == "write":
@@ -835,131 +832,13 @@ def fill_commands(zone_pages: int, *, n_zones: int, occupancy: float,
 
 def _legacy_array(flash, zone_geom, geom: ArrayGeometry,
                   member_specs: Sequence[ElementSpec], *,
-                  max_active: int, oracle: bool = False) -> ZNSArray:
-    """The pipeline being retired: an object ``ZNSArray`` over per-op
-    ``ZNSDevice`` shims (what ``ZNSArray.build`` constructs -- one
-    engine dispatch per member op), each member built with its actual
-    spec.  ``oracle=True`` swaps in ``LegacyZNSDevice`` members -- the
-    bit-compatible pure-numpy oracle, cheap enough to differential-check
-    every array."""
-    if oracle:
-        from repro.core.device_legacy import LegacyZNSDevice as cls
-    else:
-        from repro.core.device import ZNSDevice as cls
-    devices = [cls(flash, zone_geom, s, max_active=max_active)
+                  max_active: int) -> ZNSArray:
+    """The differential oracle: an object ``ZNSArray`` over
+    ``LegacyZNSDevice`` members -- the bit-compatible pure-numpy
+    device, cheap enough to check every array -- each member built with
+    its actual spec."""
+    from repro.core.device_legacy import LegacyZNSDevice
+
+    devices = [LegacyZNSDevice(flash, zone_geom, s, max_active=max_active)
                for s in member_specs]
     return ZNSArray(devices, geom)
-
-
-def array_vs_legacy_speedup(*, n_arrays: int = 8, repeats: int = 3,
-                            flash=None, zone_geom=None,
-                            specs: Optional[Sequence[ElementSpec]] = None,
-                            max_active: int = 14, n_zones: int = 4,
-                            legacy_arrays: Optional[int] = None
-                            ) -> Dict[str, float]:
-    """Time the engine-native array path against the object ``ZNSArray``
-    replay -- the ``array`` section of ``BENCH_fleet.json``.
-
-    Both paths run the *same* logical commands (a devices x chunk x
-    parity sweep of fill/FINISH/churn workloads).  The engine leg is
-    the tentpole's product: the commands are compiled ONCE into
-    encoded member programs (``build_s``, reported separately -- the
-    compiled program is a reusable artifact, like an XLA executable),
-    then each timed repeat is one batched ``run_array_batch`` dispatch
-    plus the full per-array ``report()`` decode.  The legacy leg
-    replays the commands through object arrays over per-op
-    ``LegacyZNSDevice`` members; with ``legacy_arrays`` < ``n_arrays``
-    it is timed once on that prefix and scaled (recorded honestly in
-    the returned fields: ``legacy_timed_arrays`` / ``legacy_measured_s``
-    / ``legacy_scale``).  Before any timing, every per-array report is
-    asserted bit-identical between the paths (the exactness oracle).
-    """
-    from repro.core.geometry import zn540
-
-    if (flash is None) != (zone_geom is None):
-        raise ValueError("flash and zone_geom must be given together")
-    if flash is None:
-        flash, zone_geom = zn540()
-    specs = tuple(specs) if specs else (SUPERBLOCK,)
-    eng = ZoneEngine(flash, zone_geom,
-                     specs if len(specs) > 1 else specs[0],
-                     max_active=max_active)
-    seg = zone_geom.segment_pages(flash)
-    axis = [(n_dev, chunk, parity)
-            for n_dev in (4, 3)
-            for chunk in (seg, seg // 2)
-            for parity in (True, False)]
-    arrays: List[ArrayEngine] = []
-    commands: List[List[Command]] = []
-    t0 = time.perf_counter()
-    for i in range(n_arrays):
-        n_dev, chunk, parity = axis[i % len(axis)]
-        member_specs = tuple(specs[d % len(specs)] for d in range(n_dev))
-        a = ArrayEngine(eng, ArrayGeometry(n_dev, chunk, parity),
-                        member_specs=member_specs,
-                        max_active=max_active)
-        occ = 0.4 + 0.2 * (i % 3)
-        cmds = fill_commands(a.zone_pages, n_zones=n_zones,
-                             occupancy=occ, churn=2)
-        apply_commands(a, cmds)
-        arrays.append(a)
-        commands.append(cmds)
-    build_s = time.perf_counter() - t0
-
-    def engine_pass():
-        run_array_batch(arrays, pad_quantum=64)
-        return [a.report() for a in arrays]
-
-    def legacy_pass(subset, *, oracle=False):
-        reports = []
-        for a, cmds in subset:
-            arr = _legacy_array(flash, zone_geom, a.geom, a.member_specs,
-                                max_active=max_active, oracle=oracle)
-            apply_commands(arr, cmds)
-            reports.append(arr.report())
-        return reports
-
-    # exactness oracle (and engine warm-up): every report key of every
-    # array bit-identical to the pure-numpy object oracle before
-    # anything is timed
-    engine_reports = engine_pass()
-    oracle_reports = legacy_pass(list(zip(arrays, commands)), oracle=True)
-    for er, lr in zip(engine_reports, oracle_reports):
-        assert er.keys() == lr.keys()
-        for k in er:
-            assert er[k] == lr[k], (
-                f"engine/legacy array mismatch on {k}: "
-                f"{er[k]} vs {lr[k]}")
-
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        engine_pass()
-    engine_s = (time.perf_counter() - t0) / repeats
-
-    # the timed legacy leg is the retired pipeline itself (ZNSArray over
-    # per-op ZNSDevice shims); warmed on its prefix, timed once, scaled
-    n_leg = min(legacy_arrays or n_arrays, n_arrays)
-    scale = n_arrays / n_leg
-    prefix = list(zip(arrays, commands))[:n_leg]
-    shim_reports = legacy_pass(prefix)      # warm-up (jit caches)
-    for er, lr in zip(engine_reports, shim_reports):
-        assert er == lr, "shim-member array diverged from the engine"
-    t0 = time.perf_counter()
-    legacy_pass(prefix)
-    legacy_measured_s = time.perf_counter() - t0
-    legacy_s = legacy_measured_s * scale
-
-    lane_ops = float(sum(len(p) for a in arrays
-                         for p in a.member_programs()))
-    return {
-        "n_arrays": float(n_arrays),
-        "lane_ops": lane_ops,
-        "build_s": build_s,
-        "engine_s": engine_s,
-        "engine_total_s": build_s / max(1, repeats) + engine_s,
-        "legacy_s": legacy_s,
-        "legacy_measured_s": legacy_measured_s,
-        "legacy_timed_arrays": float(n_leg),
-        "legacy_scale": scale,
-        "speedup": legacy_s / engine_s,
-    }

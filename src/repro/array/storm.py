@@ -19,8 +19,8 @@ masked out of the clock, so makespans cover only the storm phase.
 ``rebuild_interference = contended / host`` makespan, per scenario.
 
 Repeated calls at the same scenario scale hit one compiled shape
-(``pad_quantum`` rounds the op axis), which ``tools/bench.py`` asserts
-with a ``RecompileCounter`` like the interference sweep.
+(``pad_quantum`` rounds the op axis), which
+``tests/test_array_engine.py`` asserts with a ``RecompileCounter``.
 """
 
 from __future__ import annotations

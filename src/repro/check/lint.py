@@ -25,13 +25,13 @@ hit (each rule cites the PR that paid for it):
     in shapes) or silently retraces per call.
 
 ``bench-schema``
-    A ``BENCH_<name>.json`` artifact reference that ``tools/bench.py``
-    does not actually write, or a hard-coded integer bench
-    ``schema_version`` (import ``SCHEMA_VERSION`` instead) -- stale
-    references survived two artifact renames before this gate.  Only
-    files that mention ``BENCH_*.json`` artifacts are in scope for the
-    schema-version half, so unrelated schemas (e.g. the Perfetto
-    export's) are not flagged.
+    A ``BENCH_<name>.json`` artifact reference that
+    ``benchmarks/paper_headline.py`` does not name, or a hard-coded
+    integer bench ``schema_version`` (import ``SCHEMA_VERSION``
+    instead) -- stale references survived two artifact renames before
+    this gate.  Only files that mention ``BENCH_*.json`` artifacts are
+    in scope for the schema-version half, so unrelated schemas (e.g.
+    the Perfetto export's) are not flagged.
 
 Suppress any finding with a ``# lint: ok`` comment on the flagged
 line.  Pure stdlib (``ast`` + ``tokenize``); no repro imports, so
@@ -176,8 +176,9 @@ class _Visitor(ast.NodeVisitor):
                 and self.bench_artifacts
                 and node.value not in self.bench_artifacts):
             self._add(node, "bench-schema",
-                      f"{node.value} is not an artifact tools/bench.py "
-                      f"writes ({', '.join(sorted(self.bench_artifacts))})")
+                      f"{node.value} is not an artifact "
+                      f"benchmarks/paper_headline.py writes "
+                      f"({', '.join(sorted(self.bench_artifacts))})")
         self.generic_visit(node)
 
     def visit_Compare(self, node: ast.Compare) -> None:
@@ -189,7 +190,8 @@ class _Visitor(ast.NodeVisitor):
             if has_key and has_int:
                 self._add(node, "bench-schema",
                           "hard-coded bench schema_version comparison; "
-                          "import SCHEMA_VERSION from tools/bench.py")
+                          "import SCHEMA_VERSION from "
+                          "benchmarks/paper_headline.py")
         self.generic_visit(node)
 
 
@@ -211,11 +213,11 @@ def _pragma_lines(source: str) -> Set[int]:
 
 
 def bench_artifacts(root: Path) -> Set[str]:
-    """The ``BENCH_*.json`` artifact names ``tools/bench.py`` actually
-    writes -- the canonical set every other reference is checked
-    against.  Empty (disabling the artifact-name half of
-    ``bench-schema``) when the file is absent."""
-    bench = root / "tools" / "bench.py"
+    """The ``BENCH_*.json`` artifact names
+    ``benchmarks/paper_headline.py`` writes -- the canonical set every
+    other reference is checked against.  Empty (disabling the
+    artifact-name half of ``bench-schema``) when the file is absent."""
+    bench = root / "benchmarks" / "paper_headline.py"
     if not bench.is_file():
         return set()
     return set(_BENCH_ANY.findall(bench.read_text()))
@@ -240,7 +242,7 @@ def lint_paths(root: Path, paths: Iterable[Path]) -> List[Finding]:
     names = bench_artifacts(root)
     out: List[Finding] = []
     for p in sorted(paths):
-        if p.name == "bench.py" and p.parent.name == "tools":
+        if p.name == "paper_headline.py" and p.parent.name == "benchmarks":
             continue  # the canonical artifact list itself
         rel = str(p.relative_to(root)) if p.is_relative_to(root) else str(p)
         out.extend(lint_source(p.read_text(), rel, bench_names=names))

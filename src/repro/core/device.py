@@ -70,13 +70,11 @@ class ZNSDevice:
                  spec: ElementSpec,
                  *,
                  max_active: int = 14,
-                 alloc_impl: str = "xla",
                  wear_aware: Optional[bool] = None):
         self.flash = flash
         self.zone_geom = zone_geom
         self.spec = spec
         self.max_active = max_active
-        self.alloc_impl = alloc_impl  # kept for API compat; engine uses XLA
 
         self.engine = zengine.ZoneEngine(
             flash, zone_geom, spec, max_active=max_active,

@@ -3,9 +3,8 @@
 This is the pre-engine implementation of :class:`ZNSDevice`, kept verbatim
 (modulo the class rename) as the reference/oracle for the pytree
 :mod:`repro.core.engine` core: the differential property tests replay
-random op sequences through both and require bit-identical state, and
-``tools/bench.py`` uses it as the per-op-loop baseline when measuring the
-scan-compiled engine's speedup.  New code should use
+random op sequences through both and require bit-identical state.  New
+code should use
 :class:`repro.core.device.ZNSDevice` (the engine-backed shim) instead.
 """
 

@@ -26,8 +26,8 @@ Figures (each one dispatch, shapes stable across repeats):
   fleet timing model (FINISH padding is real program traffic).
 
 :func:`paper_report` assembles all three plus a recompile-stability
-probe into the ``BENCH_paper.json`` artifact gated by
-``tools/bench.py``; ``benchmarks/paper_headline.py`` is the CLI.
+probe into the ``BENCH_paper.json`` artifact;
+``benchmarks/paper_headline.py`` is the CLI that writes and gates it.
 The per-occupancy DLWA points are differentially tested against the
 per-op ``LegacyZNSDevice`` oracle at small geometry in
 ``tests/test_engine_diff.py``.

@@ -13,30 +13,27 @@ Each benchmark also has a **batched engine driver** (``*_engine`` /
 executes it through :mod:`repro.core.engine` -- a whole occupancy sweep
 runs as one vmapped ``lax.scan`` instead of per-op Python calls, and the
 interference benchmark runs as a single fused finish+host-write program.
-The engine drivers are metric-identical to the per-op paths (tested) and
-are what ``tools/bench.py`` uses to track the engine-vs-legacy speedup.
+The engine drivers are metric-identical to the per-op paths
+(differential-tested against ``LegacyZNSDevice`` in
+``tests/test_engine_diff.py``).
 """
 
 from __future__ import annotations
 
-import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence
 
-import jax
 import numpy as np
 
 from repro.core import engine as zengine
 from repro.core import timing
-from repro.core.device import IOTrace, ZNSDevice, ZoneState
+from repro.core.device import IOTrace, ZNSDevice
 from repro.core.elements import ElementSpec
 from repro.core.geometry import FlashGeometry, ZoneGeometry
 
 
 def make_device(flash: FlashGeometry, zone: ZoneGeometry, spec: ElementSpec,
-                *, max_active: int = 14, alloc_impl: str = "xla") -> ZNSDevice:
-    return ZNSDevice(flash, zone, spec, max_active=max_active,
-                     alloc_impl=alloc_impl)
+                *, max_active: int = 14) -> ZNSDevice:
+    return ZNSDevice(flash, zone, spec, max_active=max_active)
 
 
 # --------------------------------------------------------------------- #
@@ -311,64 +308,6 @@ def interference_benchmark_engine(eng: zengine.ZoneEngine, *,
     }
 
 
-def interference_sweep_engine(eng: zengine.ZoneEngine,
-                              concurrencies: Sequence[int], *,
-                              fill_occupancy: float = 0.4,
-                              host_pages_per_zone: Optional[int] = None
-                              ) -> List[Dict[str, float]]:
-    """The whole concurrency sweep of
-    :func:`interference_benchmark_engine` in ONE batched dispatch.
-
-    The per-concurrency driver rebuilt a ``3 * c``-row program per
-    point, so every concurrency was its own ``run_program`` shape --
-    one jaxpr trace + compile *per point* plus per-point dispatch
-    overhead, which is why ``BENCH_zoneengine.json`` showed the engine
-    *losing* to the legacy loop (0.96x) on this benchmark.  Here the
-    per-concurrency programs are NOP-padded to one rectangular batch
-    and executed through a single ``run_programs`` dispatch: one
-    compiled shape for the whole sweep, verified recompile-free across
-    repeats by the ``repro.obs`` recompile counter in ``tools/bench.py``
-    and ``tests/test_obs.py``.
-
-    Per-point metrics (stream rebuild + ``run_trace`` timing on the
-    unpadded prefix) are exactly those of
-    :func:`interference_benchmark_engine` (asserted in tests and by
-    ``tools/bench.py`` against the legacy per-op loop).
-    """
-    concurrencies = list(concurrencies)
-    progs = [interference_program(
-        eng, concurrency=c, fill_occupancy=fill_occupancy,
-        host_pages_per_zone=host_pages_per_zone) for c in concurrencies]
-    n_max = max((len(p) for p in progs), default=0)
-    batch = np.zeros((len(progs), n_max, 4), dtype=np.int32)
-    for i, p in enumerate(progs):
-        batch[i, : len(p)] = p                 # NOP rows pad the tail
-    _, traces = eng.run_batch(eng.init_state(), batch)
-    out: List[Dict[str, float]] = []
-    for i, (c, prog) in enumerate(zip(concurrencies, progs)):
-        lane = jax.tree_util.tree_map(lambda x: x[i], traces)
-        streams = _op_traces(eng, prog, lane)
-        host_traces = [t for t in streams[c: 2 * c] if t is not None]
-        finish_traces = [t for t in streams[2 * c: len(prog)]
-                         if t is not None and len(t.luns)]
-        base = timing.run_trace(eng.flash, host_traces)
-        base_tp = sum(base[f"owner{j}_throughput_pages_s"]
-                      for j in range(len(host_traces)))
-        cont = timing.run_trace(eng.flash, host_traces + finish_traces)
-        cont_tp = sum(cont[f"owner{j}_throughput_pages_s"]
-                      for j in range(len(host_traces)))
-        out.append({
-            "concurrency": float(c),
-            "baseline_pages_s": base_tp,
-            "contended_pages_s": cont_tp,
-            "interference": base_tp / cont_tp if cont_tp else
-            float("inf"),
-            "dummy_pages": float(sum(len(t.luns)
-                                     for t in finish_traces)),
-        })
-    return out
-
-
 def write_program(eng: zengine.ZoneEngine, *, request_kib: int,
                   n_jobs: int, mib_per_job: int = 16, zone_base: int = 0,
                   zone_pages: Optional[int] = None) -> np.ndarray:
@@ -401,97 +340,4 @@ def write_benchmark_engine(eng: zengine.ZoneEngine, *, request_kib: int,
         "pages": float(stats["n"]),
         "bandwidth_mib_s": timing.write_bandwidth_mib_s(eng.flash, stats),
         "makespan_s": stats["makespan_s"],
-    }
-
-
-# --------------------------------------------------------------------- #
-# Engine vs legacy per-op loop: the PR's tracked perf trajectory
-# --------------------------------------------------------------------- #
-def engine_vs_legacy_speedup(*, occupancies: Sequence[float] = tuple(
-        np.linspace(0.05, 0.95, 16)), n_zones: int = 8,
-        concurrencies: Sequence[int] = (1, 2, 4, 7),
-        repeats: int = 3) -> Dict[str, float]:
-    """Time the DLWA occupancy sweep and the interference benchmark on
-    the legacy per-op ``LegacyZNSDevice`` loop vs the scan-compiled
-    engine (steady state: compile excluded via warmup).  Returns ops/sec
-    for both plus the speedups ``tools/bench.py`` archives."""
-    from repro.core.device_legacy import LegacyZNSDevice
-    from repro.core.elements import SUPERBLOCK
-    from repro.core.geometry import zn540
-
-    flash, zone = zn540()
-    eng = make_engine(flash, zone, SUPERBLOCK, max_active=28)
-
-    # ---- dlwa sweep -------------------------------------------------- #
-    n_ops_dlwa = 2 * n_zones * len(occupancies)
-    dlwa_sweep_engine(eng, occupancies, n_zones=n_zones)  # compile
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        eng_rows = dlwa_sweep_engine(eng, occupancies, n_zones=n_zones)
-    t_eng_dlwa = (time.perf_counter() - t0) / repeats
-
-    def legacy_sweep():
-        rows = []
-        for occ in occupancies:
-            dev = LegacyZNSDevice(flash, zone, SUPERBLOCK, max_active=28)
-            rows.append(dlwa_benchmark(dev, occupancy=occ,
-                                       n_zones=n_zones))
-        return rows
-    legacy_sweep()  # warm the allocator jit
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        leg_rows = legacy_sweep()
-    t_leg_dlwa = (time.perf_counter() - t0) / repeats
-    assert [r["dlwa"] for r in eng_rows] == [r["dlwa"] for r in leg_rows]
-
-    # ---- interference (whole sweep in ONE padded dispatch) ------------ #
-    # the per-concurrency driver compiled one run_program shape per
-    # point, which is what regressed this benchmark to 0.96x pre-PR 6;
-    # the batched sweep holds one run_programs shape for the whole
-    # sweep, and the obs recompile counter certifies repeats are
-    # compile-free
-    from repro.obs.profile import RecompileCounter
-    n_ops_intf = sum(3 * c for c in concurrencies)
-
-    def engine_intf():
-        return interference_sweep_engine(eng, concurrencies)
-
-    def legacy_intf():
-        out = []
-        for c in concurrencies:
-            dev = LegacyZNSDevice(flash, zone, SUPERBLOCK, max_active=28)
-            out.append(interference_benchmark(dev, concurrency=c))
-        return out
-    engine_intf(); legacy_intf()  # compile both paths
-    rc = RecompileCounter(run_programs=zengine.run_programs)
-    warm = rc.counts()
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        ei = engine_intf()
-    t_eng_intf = (time.perf_counter() - t0) / repeats
-    intf_recompiles = rc.delta(warm)["run_programs"]
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        li = legacy_intf()
-    t_leg_intf = (time.perf_counter() - t0) / repeats
-    assert [r["dummy_pages"] for r in ei] == [r["dummy_pages"] for r in li]
-
-    return {
-        "dlwa_ops": float(n_ops_dlwa),
-        "dlwa_legacy_s": t_leg_dlwa,
-        "dlwa_engine_s": t_eng_dlwa,
-        "dlwa_legacy_ops_s": n_ops_dlwa / t_leg_dlwa,
-        "dlwa_engine_ops_s": n_ops_dlwa / t_eng_dlwa,
-        "dlwa_speedup": t_leg_dlwa / t_eng_dlwa,
-        "interference_ops": float(n_ops_intf),
-        "interference_legacy_s": t_leg_intf,
-        "interference_engine_s": t_eng_intf,
-        "interference_legacy_ops_s": n_ops_intf / t_leg_intf,
-        "interference_engine_ops_s": n_ops_intf / t_eng_intf,
-        "interference_speedup": t_leg_intf / t_eng_intf,
-        # dispatches per sweep and jit-cache growth across the timed
-        # repeats (0 = shape-stable, the property the batched sweep
-        # restores; tools/bench.py asserts it)
-        "interference_dispatches": 1.0,
-        "interference_recompiles": float(intf_recompiles),
     }

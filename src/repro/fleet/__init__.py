@@ -23,10 +23,7 @@ The fleet layer turns the repo from "replay the paper's sweeps" into
   Pareto archive, and seeded determinism.
 
 Entry points: ``benchmarks/fleet_search.py --strategy {grid,random,
-evolve}`` (the sweep), ``examples/fleet.py`` (a small demo),
-``tools/bench.py`` (writes the batched-vs-legacy speedup and the
-evolve-vs-random dispatches-to-target comparison to
-``BENCH_fleet.json``; ``--skip-engine`` isolates the fleet part).
+evolve}`` (the sweep), ``examples/fleet.py`` (a small demo).
 """
 
 from repro.fleet.evolve import (EvolveParams, EvolveResult, evolve,

@@ -8,8 +8,7 @@ candidates by mutation/crossover on the :class:`SearchSpace` gene
 vector (tenant mix, effective segments, stripe chunk, parity,
 wear-awareness), and every generation is scored through the shared
 :class:`~repro.fleet.search.Evaluator` -- ONE batched ``run_programs``
-dispatch per rung, exploiting the ~26x batched-vs-legacy pipeline
-``BENCH_fleet.json`` tracks.
+dispatch per rung.
 
 Cost control is a successive-halving (bandit) schedule inside each
 generation: the population is first evaluated on *truncated* op
@@ -30,7 +29,7 @@ Budget accounting rides the evaluator's ledger: ``n_dispatches``
 (batched evaluator invocations), ``n_evals`` (full-fidelity-equivalent
 config evaluations -- a config at fidelity *f* costs *f*), and
 ``lane_ops`` (scanned lane x op cells).  :func:`evolve_vs_random` is
-the comparison ``tools/bench.py`` archives: random search dispatched in
+the dispatches-to-target comparison: random search dispatched in
 population-sized batches vs evolve stopping at the random-best target.
 """
 
@@ -273,7 +272,7 @@ def evolve_vs_random(eng: ZoneEngine, *,
                      n_devices: int = 4,
                      weights: Tuple[float, float, float] = (1.0, 1.0, 1.0)
                      ) -> Dict:
-    """The dispatches-to-target comparison ``BENCH_fleet.json`` records.
+    """The dispatches-to-target comparison of evolve against random.
 
     Baseline: ``random_n`` configs sampled without replacement,
     evaluated at full fidelity in population-sized batches (an adaptive

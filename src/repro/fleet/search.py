@@ -471,8 +471,9 @@ class Evaluator:
 
     * ``n_dispatches`` -- :meth:`evaluate` calls issued;
     * ``n_evals``      -- full-fidelity-equivalent config evaluations
-      (a config at fidelity ``f`` costs ``f``), the unit the
-      dispatches-to-target comparison in ``BENCH_fleet.json`` uses;
+      (a config at fidelity ``f`` costs ``f``), the unit of the
+      dispatches-to-target comparison in
+      :func:`repro.fleet.evolve.evolve_vs_random`;
     * ``lane_ops``     -- scanned ``(lane, op)`` cells actually
       dispatched (lanes x padded program length), the raw compute
       proxy.
@@ -490,9 +491,8 @@ class Evaluator:
     ``recompiles`` watches the jit caches of the dispatch surface --
     :meth:`jit_cache` readings staying flat across repeated
     generations is the shape-stability property ``pad_quantum`` buys
-    (asserted in ``tests/test_obs.py``, recorded per generation by
-    ``repro.fleet.evolve`` when a profiler is attached, and archived
-    by ``tools/bench.py``).
+    (asserted in ``tests/test_obs.py``, and recorded per generation by
+    ``repro.fleet.evolve`` when a profiler is attached).
     """
 
     def __init__(self, eng: ZoneEngine, *, n_devices: int = 4,
@@ -642,14 +642,13 @@ def pareto_front(rows: List[Dict],
 
 
 # --------------------------------------------------------------------- #
-# per-op legacy comparator (the speedup baseline tools/bench.py tracks)
+# per-op legacy oracle (the reference the fleet tests compare against)
 # --------------------------------------------------------------------- #
 def run_configs_legacy(flash: FlashGeometry, spec: ElementSpec,
                        configs: Sequence[FleetConfig],
                        merged_programs: Sequence[np.ndarray], *,
                        parallelism: int, n_devices: int = 4,
-                       max_active: int = 14,
-                       fleet_timing: bool = False) -> List[Dict]:
+                       max_active: int = 14) -> List[Dict]:
     """Evaluate each config the pre-fleet way: replay its merged logical
     program through a real :class:`repro.array.ZNSArray` over per-op
     ``LegacyZNSDevice`` members.  Each config gets devices built with
@@ -657,21 +656,14 @@ def run_configs_legacy(flash: FlashGeometry, spec: ElementSpec,
     (``fc.spec``; the ``spec`` argument is only the engine's primary
     and is superseded per config), so this doubles as a semantic
     cross-check: array DLWA must match the batched engine path exactly
-    (tested, and asserted by ``tools/bench.py``) -- including
+    (``tests/test_fleet.py``, ``tests/test_union_spec.py``) -- including
     mixed-spec batches through a union config.  ``alloc_policy =
     "silent"`` configs replay here too: which blocks a zone claims
     never changes which pages FINISH pads (pads depend only on the
     write pointer and the spec's stripe map), so silent lanes are
     DLWA-identical to the legacy device at the same spec (wear totals
-    are where the policies diverge, and those are not replayed).
-
-    With ``fleet_timing`` the replay also collects the page-granular IO
-    traces and runs :func:`repro.core.timing.run_fleet_trace` per
-    config -- the full evaluation pipeline ``benchmarks/raid_zns.py``
-    established in PR 1, and the baseline the ``BENCH_fleet.json``
-    speedup is measured against."""
+    are where the policies diverge, and those are not replayed)."""
     from repro.array import ArrayGeometry, ZNSArray
-    from repro.core import timing
     from repro.core.device_legacy import LegacyZNSDevice
 
     out = []
@@ -687,16 +679,13 @@ def run_configs_legacy(flash: FlashGeometry, spec: ElementSpec,
                    for d in range(nd)]
         arr = ZNSArray(devices, ArrayGeometry(
             nd, fc.chunk_pages, fc.parity))
-        tagged: List = []
         for row in merged:
             op, zone, n_pages = int(row[0]), int(row[1]), int(row[2])
             if op == zengine.OP_WRITE:
-                tr = arr.zone_write(zone, n_pages,
-                                    host=bool(row[3] & zengine.F_HOST),
-                                    trace=fleet_timing)
-                tagged += tr or []
+                arr.zone_write(zone, n_pages,
+                               host=bool(row[3] & zengine.F_HOST))
             elif op == zengine.OP_FINISH:
-                tagged += arr.zone_finish(zone, trace=fleet_timing) or []
+                arr.zone_finish(zone)
             elif op == zengine.OP_RESET:
                 arr.zone_reset(zone)
         rep = arr.report()
@@ -706,121 +695,5 @@ def run_configs_legacy(flash: FlashGeometry, spec: ElementSpec,
         # blocks_per_element times, which leaves the CV unchanged)
         w = np.concatenate([d.block_wear() for d in arr.devices])
         rep["wear_cv"] = float(w.std() / w.mean()) if w.mean() > 0 else 0.0
-        if fleet_timing:
-            fleet = timing.run_fleet_trace(
-                arr.flash, timing.group_tagged(tagged, nd))
-            rep["makespan_s"] = fleet["fleet_makespan_s"]
-            rep["fleet_pages"] = float(fleet["n"])
         out.append(rep)
     return out
-
-
-def fleet_vs_legacy_speedup(*, n_devices: int = 4,
-                            configs: Optional[Sequence[FleetConfig]] = None,
-                            repeats: int = 3,
-                            flash: Optional[FlashGeometry] = None,
-                            zone_geom: Optional[ZoneGeometry] = None,
-                            max_active: int = 14,
-                            specs: Optional[Sequence[ElementSpec]] = None,
-                            legacy_configs: Optional[int] = None
-                            ) -> Dict[str, float]:
-    """Time the batched fleet sweep against the per-op legacy pipeline.
-
-    Both paths evaluate the *same* configs on the *same* logical
-    traffic (the merged tenant programs), end to end:
-
-    * **engine** -- :func:`evaluate_configs`: ONE ``run_programs``
-      dispatch over all (config x device) lanes + ONE batched
-      op-granular timing dispatch;
-    * **legacy** -- :func:`run_configs_legacy` with ``fleet_timing``:
-      per config, a real ``ZNSArray`` over stateful-Python members,
-      page-granular trace collection, and a ``run_fleet_trace`` device
-      simulation -- exactly the evaluation pipeline
-      ``benchmarks/raid_zns.py`` established in PR 1.
-
-    Steady state (compile excluded via one warm pass); array-level DLWA
-    is asserted identical between the paths before anything is timed.
-    Also reports the replay-only legacy time (``legacy_replay_s``, no
-    trace/timing) so the artifact separates state-machine cost from the
-    page-granular timing cost the legacy path is stuck with.  With
-    ``specs`` (a spec set) the engine is the padded *union* config and
-    the configs may mix element specs per lane -- the legacy path then
-    builds each config's members with its actual spec, making the DLWA
-    assert an exactness oracle for the mixed-spec dispatch.  Returns
-    the numbers ``tools/bench.py`` archives in ``BENCH_fleet.json``.
-
-    ``legacy_configs`` (< the config count) times the legacy legs on
-    only that config prefix, once, and linearly scales the measurement
-    -- the per-op pipeline is per-config sequential, so its cost is
-    linear in the config count, and timing all K at full repeats just
-    burns bench minutes.  The scaling is recorded honestly:
-    ``legacy_timed_configs``, the measured times
-    (``legacy_measured_s`` / ``legacy_replay_measured_s``) and
-    ``legacy_scale`` all land in the returned dict (and the artifact).
-    The DLWA exactness assert always covers EVERY config.
-    """
-    import time
-
-    from repro.core.geometry import zn540
-
-    if (flash is None) != (zone_geom is None):
-        raise ValueError("flash and zone_geom must be given together")
-    if flash is None:
-        flash, zone_geom = zn540()
-    specs = tuple(specs) if specs else (SUPERBLOCK,)
-    eng = ZoneEngine(flash, zone_geom,
-                     specs if len(specs) > 1 else specs[0],
-                     max_active=max_active)
-    if configs is None:
-        configs = grid_space(specs=specs)
-    programs, dyn, merged = build_fleet_batch(eng, configs,
-                                              n_devices=n_devices)
-    n_ops = int((programs[:, :, 0] != zengine.OP_NOP).sum())
-
-    def engine_pass():
-        return evaluate_configs(eng, configs, n_devices=n_devices)
-
-    def legacy_pass(fleet_timing=True, n=None):
-        return run_configs_legacy(
-            flash, specs[0], configs[:n], merged[:n],
-            parallelism=zone_geom.parallelism, n_devices=n_devices,
-            max_active=max_active, fleet_timing=fleet_timing)
-
-    rows = engine_pass()      # compile/warm both paths
-    legacy = legacy_pass()    # EVERY config: the exactness oracle
-    for r, l in zip(rows, legacy):
-        assert abs(r["dlwa"] - l["dlwa"]) < 1e-9, (
-            f"engine/legacy DLWA mismatch on {r['config']}: "
-            f"{r['dlwa']} vs {l['dlwa']}")
-
-    def timed(fn, reps=repeats):
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            fn()
-        return (time.perf_counter() - t0) / reps
-
-    n_leg = min(legacy_configs or len(configs), len(configs))
-    scale = len(configs) / n_leg
-    leg_reps = repeats if n_leg == len(configs) else 1
-    t_eng = timed(engine_pass)
-    t_leg_measured = timed(lambda: legacy_pass(n=n_leg), leg_reps)
-    t_leg_replay_measured = timed(
-        lambda: legacy_pass(fleet_timing=False, n=n_leg), leg_reps)
-    t_leg = t_leg_measured * scale
-    t_leg_replay = t_leg_replay_measured * scale
-    return {
-        "n_configs": float(len(configs)),
-        "n_devices": float(n_devices),
-        "fleet_ops": float(n_ops),
-        "legacy_s": t_leg,
-        "legacy_replay_s": t_leg_replay,
-        "legacy_measured_s": t_leg_measured,
-        "legacy_replay_measured_s": t_leg_replay_measured,
-        "legacy_timed_configs": float(n_leg),
-        "legacy_scale": scale,
-        "engine_s": t_eng,
-        "legacy_configs_s": len(configs) / t_leg,
-        "engine_configs_s": len(configs) / t_eng,
-        "speedup": t_leg / t_eng,
-        "replay_speedup": t_leg_replay / t_eng,
-    }

@@ -36,11 +36,11 @@ model:
 
 Entry points: ``benchmarks/fleet_search.py --obs`` (emit trace +
 telemetry for a search run), ``tools/obs_report.py`` (render the
-telemetry as a markdown report), ``tools/bench.py`` (telemetry overhead
-and recompile-stability sections of the BENCH artifacts).  The recorder
-is effect-free on device results: telemetry-on and telemetry-off runs
-produce bit-identical ``DeviceState`` / ``OpTrace`` (property-tested in
-``tests/test_obs.py``).
+telemetry as a markdown report), and ``bench/run.py --trace 1`` (the
+chip benchmark's per-layer metrics, read from the program spans).  The
+recorder is effect-free on device results: telemetry-on and
+telemetry-off runs produce bit-identical ``DeviceState`` /
+``OpTrace`` (property-tested in ``tests/test_obs.py``).
 """
 
 from repro.obs.export import (MetricsRegistry, emit_fleet_obs,

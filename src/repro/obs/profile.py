@@ -15,9 +15,8 @@ Three instruments, all cheap enough to leave on:
   watches (``fn._cache_size()``, keyed on abstract input signatures:
   shapes/dtypes + static args).  A stable count across repeated
   dispatches proves shape stability (the property
-  ``Evaluator.pad_quantum`` exists to buy); a growing count is the
-  recompile leak the ROADMAP's interference regression turned out to
-  be (see ``workloads.interference_sweep_engine``).
+  ``Evaluator.pad_quantum`` exists to buy); a growing count is a
+  recompile leak (one compiled shape per call).
 * :func:`span` -- the one call library code uses to mark its host
   work (and :func:`count`, the one it adds to a program counter
   with).  A :class:`Profiler` section makes its profiler the *current*

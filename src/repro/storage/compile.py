@@ -35,7 +35,7 @@ this module registers each as a tenant mix in
 scores allocator/geometry configs against realistic application
 traffic through the unchanged grid/random/evolve machinery, and
 :func:`run_workload` emits the class-tagged dispatch + report that
-``BENCH_fleet.json`` and the CI artifact carry.
+the CI artifact (``fleet_workload_<name>.json``) carries.
 """
 
 from __future__ import annotations

@@ -15,8 +15,7 @@ import numpy as np
 import pytest
 
 from repro.array import (ArrayEngine, ArrayGeometry, StormScenario,
-                         ZNSArray, apply_commands,
-                         array_vs_legacy_speedup, fill_commands,
+                         ZNSArray, apply_commands, fill_commands,
                          rebuild_storm, run_array_batch)
 from repro.array.engine import _legacy_array
 from repro.core import engine as E
@@ -44,8 +43,7 @@ def build_pair(n_devices, *, chunk_pages=None, parity=False,
                                 max_active=max_active,
                                 wear_aware=wear_aware)
     legacy = _legacy_array(flash, zone, eng_arr.geom,
-                           eng_arr.member_specs, max_active=max_active,
-                           oracle=True)
+                           eng_arr.member_specs, max_active=max_active)
     if wear_aware is not None:
         for d in legacy.devices:
             d.wear_aware = wear_aware
@@ -246,20 +244,6 @@ def test_fleet_timing_per_op_read_write_rates():
         flash.n_luns, 1)
     assert np.array_equal(np.asarray(scalar), np.asarray(perop))
     del ops
-
-
-def test_speedup_comparator_smoke():
-    """The BENCH array pipeline end to end on the tiny geometry --
-    exactness is asserted inside over every array."""
-    flash, zone = tiny_geoms()
-    rep = array_vs_legacy_speedup(
-        n_arrays=2, repeats=1, flash=flash, zone_geom=zone,
-        max_active=6, n_zones=2, legacy_arrays=1)
-    for key in ("n_arrays", "lane_ops", "engine_s", "legacy_s",
-                "legacy_measured_s", "legacy_timed_arrays",
-                "legacy_scale", "speedup"):
-        assert key in rep, key
-    assert rep["legacy_scale"] == 2.0
 
 
 # --------------------------------------------------------------------- #

@@ -30,7 +30,8 @@ from repro.check import (ERR_ACTIVE_LIMIT, ERR_ALLOC_INFEASIBLE, ERR_FULL,
                          assert_state, assert_states, check_state,
                          check_states, explain_op, validate_rows,
                          verify_program, verify_programs)
-from repro.check.lint import Finding, lint_source, lint_tree
+from repro.check.lint import (Finding, bench_artifacts, lint_source,
+                              lint_tree)
 from repro.core import engine as E
 from repro.core.device import ZNSDevice
 from repro.core.elements import BLOCK, FIXED, SUPERBLOCK, hchunk, vchunk
@@ -480,18 +481,25 @@ def test_lint_jit_needs_static():
 
 
 def test_lint_bench_schema():
-    names = {"BENCH_fleet.json"}
+    names = {"BENCH_paper.json"}
     src = "p = root / 'BENCH_stale.json'\n"
     assert rules(src, bench_names=names) == ["bench-schema"]
-    assert rules("p = root / 'BENCH_fleet.json'\n",
+    assert rules("p = root / 'BENCH_paper.json'\n",
                  bench_names=names) == []
     # hard-coded schema_version comparisons only flagged in files that
     # reference bench artifacts (the Perfetto export's own schema with
     # no bench mention stays clean)
     versioned = "ok = artifact['schema_version'] == 5\n"
     assert rules(versioned, bench_names=names) == []
-    assert rules("# BENCH_fleet.json reader\n" + versioned,
+    assert rules("# BENCH_paper.json reader\n" + versioned,
                  bench_names=names) == ["bench-schema"]
+
+
+def test_bench_artifacts_reads_the_paper_writer():
+    """The rule's artifact names come from the one writer; an empty set
+    would switch the artifact-name half of ``bench-schema`` off."""
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert bench_artifacts(root) == {"BENCH_paper.json"}
 
 
 def test_lint_pragma_suppresses():

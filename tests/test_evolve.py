@@ -12,8 +12,7 @@ Four guarantees:
 4. the acceptance bar: on a seeded 2-tenant x 4-device fleet, evolve
    reaches an objective <= the best of a 32-config random search using
    <= half the random baseline's batched-evaluator budget (dispatches
-   AND full-fidelity-equivalent evals), as recorded in
-   ``BENCH_fleet.json`` by ``tools/bench.py``.
+   AND full-fidelity-equivalent evals).
 """
 
 import math

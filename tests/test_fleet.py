@@ -294,23 +294,6 @@ def test_engine_path_matches_legacy_array_replay():
         assert mine["wear_cv"] == pytest.approx(rep["wear_cv"]), fc
 
 
-def test_fleet_vs_legacy_speedup_smoke():
-    """The BENCH_fleet pipeline end to end on a tiny geometry: both
-    paths agree on DLWA (asserted inside) and the report carries every
-    field tools/bench.py archives."""
-    from repro.fleet.search import fleet_vs_legacy_speedup
-
-    configs = [FleetConfig("dlwa_pair", 4, 8, True, True),
-               FleetConfig("dlwa_write", 2, 16, False, False)]
-    rep = fleet_vs_legacy_speedup(
-        configs=configs, repeats=1, n_devices=3,
-        flash=tiny_flash(), zone_geom=ZoneGeometry(4, 4), max_active=6)
-    assert rep["n_configs"] == 2.0
-    for key in ("legacy_s", "legacy_replay_s", "engine_s", "speedup",
-                "replay_speedup", "fleet_ops"):
-        assert rep[key] > 0, key
-
-
 def test_fleet_timing_sane():
     eng = tiny_engine()
     prog = tag_tenant(workloads.dlwa_program(eng, occupancy=0.5,

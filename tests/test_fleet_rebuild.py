@@ -141,7 +141,7 @@ def _object_array(eng, fc, merged, at_row):
     seg = eng.zone_geom.parallelism * eng.flash.pages_per_block
     arr = _legacy_array(eng.flash, ZoneGeometry(4, fc.n_segments),
                         ArrayGeometry(ND, CHUNK, True), (fc.spec,) * ND,
-                        max_active=6, oracle=True)
+                        max_active=6)
     assert arr.dev_zone_pages == seg * fc.n_segments
     for d in arr.devices:
         d.wear_aware = fc.wear_aware

@@ -437,7 +437,13 @@ def test_profiler_sections_accumulate():
 
 
 def test_recompile_counter_sees_new_shapes():
-    eng = tiny_engine(SUPERBLOCK)
+    # run_program's jit cache is process-global, so the engine config
+    # (12 blocks per LUN) is one no other test builds: a file that ran
+    # earlier on the same worker cannot have compiled these shapes
+    eng = E.ZoneEngine(
+        FlashGeometry(n_channels=4, ways_per_channel=1, blocks_per_lun=12,
+                      pages_per_block=4, page_bytes=4096),
+        ZoneGeometry(4, 2), SUPERBLOCK, max_active=3)
     rc = RecompileCounter(run_program=E.run_program)
     assert jit_cache_size(E.run_program) >= 0
     p1 = _mixed_program(eng, n=10)

@@ -1,11 +1,12 @@
-"""Unit tests for the ``tools/bench.py`` paper-headline gate logic.
+"""Unit tests for ``benchmarks/paper_headline.py``, the one writer of
+``BENCH_paper.json``, and its paper-headline gates.
 
 ``check_paper_gates`` is a pure function of the ``BENCH_paper.json``
 artifact dict, so the pass/fail semantics (and the stderr WARNING
 surface CI greps) are testable without running any benchmark: a
 synthetic failing section must exit non-zero, a passing one zero.
-The ``tools/bench_table.py`` paper rows are checked against the same
-synthetic artifacts (including the pre-schema re-run message).
+``main`` is driven over the same synthetic artifacts: its exit code is
+the gates', and the file it writes carries the schema version.
 """
 
 import importlib.util
@@ -26,9 +27,8 @@ def _load(name, path):
     return mod
 
 
-bench = _load("_bench_under_test", _ROOT / "tools" / "bench.py")
-bench_table = _load("_bench_table_under_test",
-                    _ROOT / "tools" / "bench_table.py")
+paper_headline = _load("_paper_headline_under_test",
+                       _ROOT / "benchmarks" / "paper_headline.py")
 
 
 def _artifact(**over):
@@ -47,14 +47,14 @@ def _artifact(**over):
                  "cycles": 4.0, "traditional_s": 392.0,
                  "silent_s": 124.7, "host_pages": 162201.0},
         "recompiles": {"delta_total": 0.0, "entries": {}, "delta": {}},
-        "meta": {"schema_version": bench.SCHEMA_VERSION},
+        "meta": {"schema_version": paper_headline.SCHEMA_VERSION},
     }
     art.update(over)
     return art
 
 
 def test_passing_artifact_exits_zero(capsys):
-    assert bench.check_paper_gates(_artifact()) == 0
+    assert paper_headline.check_paper_gates(_artifact()) == 0
     assert capsys.readouterr().err == ""
 
 
@@ -69,7 +69,7 @@ def test_passing_artifact_exits_zero(capsys):
 def test_failing_section_exits_nonzero(capsys, section, bad, phrase):
     art = _artifact()
     art[section] = {**art[section], **bad}
-    assert bench.check_paper_gates(art) == 1
+    assert paper_headline.check_paper_gates(art) == 1
     err = capsys.readouterr().err
     assert err.startswith("WARNING:") and phrase in err
 
@@ -78,8 +78,9 @@ def test_gate_floors_are_inclusive_exclusive_as_documented(capsys):
     """The DLWA floor is inclusive (>= 80%); wear and speedup floors
     are strict (> 0, > 1x)."""
     art = _artifact()
-    art["dlwa"]["reduction_at_10pct"] = bench.PAPER_DLWA_REDUCTION_FLOOR
-    assert bench.check_paper_gates(art) == 0
+    floor = paper_headline.PAPER_DLWA_REDUCTION_FLOOR
+    art["dlwa"]["reduction_at_10pct"] = floor
+    assert paper_headline.check_paper_gates(art) == 0
     capsys.readouterr()
 
 
@@ -89,7 +90,7 @@ def test_every_failed_gate_gets_its_own_warning(capsys):
         wear={**_artifact()["wear"], "wear_reduction": -0.5},
         exec={**_artifact()["exec"], "speedup": 0.5},
         recompiles={"delta_total": 3.0})
-    assert bench.check_paper_gates(art) == 1
+    assert paper_headline.check_paper_gates(art) == 1
     warnings = [ln for ln in capsys.readouterr().err.splitlines()
                 if ln.startswith("WARNING:")]
     assert len(warnings) == 4
@@ -115,7 +116,7 @@ def test_paper_report_feeds_the_gates_end_to_end(capsys):
         == rep["dlwa"]["dlwa_reduction"][0]
     assert rep["wear"]["traditional_erases"] > rep["wear"]["silent_erases"]
     assert rep["exec"]["speedup"] > 1.0
-    assert bench.check_paper_gates(rep) in (0, 1)
+    assert paper_headline.check_paper_gates(rep) in (0, 1)
     capsys.readouterr()
 
 
@@ -135,32 +136,23 @@ def test_repo_artifact_passes_the_gates(capsys):
     path = _ROOT / "BENCH_paper.json"
     if not path.exists():
         pytest.skip("BENCH_paper.json not generated in this checkout")
-    assert bench.check_paper_gates(json.loads(path.read_text())) == 0
+    assert paper_headline.check_paper_gates(json.loads(path.read_text())) == 0
     capsys.readouterr()
 
 
-# --------------------------------------------------------------------- #
-# bench_table paper rows
-# --------------------------------------------------------------------- #
-def test_bench_table_renders_paper_rows(tmp_path):
-    p = tmp_path / "BENCH_paper.json"
-    p.write_text(json.dumps(_artifact()))
-    rows = bench_table.rows_of(p)
-    assert len(rows) == 3
-    labels = " / ".join(r[0] for r in rows)
-    assert "DLWA at 10% occupancy" in labels
-    assert "block erases" in labels
-    assert "execution time" in labels
-    assert "recompile-free" in rows[2][0]
-    assert rows[0][4] == "**-86%**"
+@pytest.mark.parametrize("reduction,rc", [(0.86, 0), (0.79, 1)],
+                         ids=["pass", "fail"])
+def test_paper_headline_exits_by_the_gates(monkeypatch, tmp_path, capsys,
+                                           reduction, rc):
+    from repro.core import headline
 
-
-def test_bench_table_rejects_pre_schema_paper_artifact(tmp_path):
-    """An artifact from an older bench (no gated 10% point) must fail
-    with the re-run message, not a KeyError."""
     art = _artifact()
-    del art["dlwa"]["reduction_at_10pct"]
-    p = tmp_path / "BENCH_paper.json"
-    p.write_text(json.dumps(art))
-    with pytest.raises(bench_table.SchemaError, match="re-run"):
-        bench_table.rows_of(p)
+    art["dlwa"]["reduction_at_10pct"] = reduction
+    del art["meta"]
+    monkeypatch.setattr(headline, "paper_report", lambda **_kw: art)
+    out = tmp_path / "BENCH_paper.json"
+    assert paper_headline.main(["--out", str(out)]) == rc
+    written = json.loads(out.read_text())
+    assert written["dlwa"]["reduction_at_10pct"] == reduction
+    assert written["meta"]["schema_version"] == paper_headline.SCHEMA_VERSION
+    assert ("DLWA reduction" in capsys.readouterr().err) == bool(rc)

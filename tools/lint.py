@@ -6,7 +6,7 @@ over ``src/``, ``tools/``, and ``tests/``.
 ::
 
     python tools/lint.py            # lint the whole repo, exit 1 if dirty
-    python tools/lint.py src/repro/fleet/search.py tools/bench.py
+    python tools/lint.py src/repro/fleet/search.py tools/obs_report.py
 
 Pure stdlib -- importing the lint rules does not import JAX, so this
 runs in CI before any accelerator setup.  Suppress a finding with a
