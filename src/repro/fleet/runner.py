@@ -49,15 +49,36 @@ class Rebuilds(NamedTuple):
                          #   row issued once its source row completed
 
 
+class FinalCounters(NamedTuple):
+    """Host copy of the final-state fields the rollups read, ``(L,
+    ...)`` numpy leaves (``elem_wear`` keeps the padded element axis
+    and its scratch slot).  One lane's slice (:meth:`lane`) carries the
+    counters :meth:`ZoneEngine.metrics` reads."""
+
+    elem_wear: np.ndarray
+    host_pages: np.ndarray
+    dummy_pages: np.ndarray
+    block_erases: np.ndarray
+    alloc_calls: np.ndarray
+    n_active: np.ndarray
+
+    def lane(self, lane: int) -> "FinalCounters":
+        return FinalCounters(*(x[lane] for x in self))
+
+
 @dataclasses.dataclass
 class FleetResult:
-    """Per-lane outputs of one batched fleet dispatch (all numpy).
+    """Per-lane outputs of one batched fleet dispatch.
 
     Lane axis ``L`` = flattened (config, device); op axis is the padded
     program length.  ``tenants`` holds the width-5 tenant column;
     parity appends carry ``parity_tenant``, a member rebuild's rows
     ``rebuilds.tenant`` (``rebuilds`` is None when no lane rebuilds);
     NOP padding moves 0 pages and is ignored by every rollup.
+
+    ``states`` (and ``telemetry``, ``dyn``) stay on the device; every
+    other array is numpy.  The rollups read the final state through
+    :meth:`final`, a host copy fetched once per result.
     """
 
     programs: np.ndarray     # (L, n_ops, 5) i32
@@ -84,17 +105,32 @@ class FleetResult:
     cfg: Optional[zengine.EngineConfig] = None
     dyn: Optional[DynConfig] = None
     rebuilds: Optional[Rebuilds] = None
+    _final: Optional[FinalCounters] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     @property
     def tenants(self) -> np.ndarray:
         return self.programs[:, :, TENANT_COL]
+
+    def final(self) -> FinalCounters:
+        """The final-state fields the rollups read, on the host: whole
+        fields in one device read on first use (counted as
+        ``rollup.device_reads``, timed as ``fleet.rollup``), then
+        cached."""
+        if self._final is None:
+            with span("fleet.rollup"):
+                count("rollup.device_reads", 1)
+                self._final = jax.device_get(FinalCounters(
+                    *(getattr(self.states, f)
+                      for f in FinalCounters._fields)))
+        return self._final
 
     def lane_wear(self, eng: ZoneEngine) -> np.ndarray:
         """(L, n_elements) element wear (erase counts) per lane, over
         the full padded static element axis (see ``elem_mask`` /
         :meth:`pooled_wear` for the per-lane real subset)."""
         n = eng.cfg.n_elements
-        return np.asarray(self.states.elem_wear[:, :n], dtype=np.int64)
+        return np.asarray(self.final().elem_wear[:, :n], dtype=np.int64)
 
     def pooled_wear(self, eng: ZoneEngine, lanes: np.ndarray
                     ) -> np.ndarray:
@@ -105,7 +141,8 @@ class FleetResult:
         the never-allocated padding so wear statistics match a device
         built with the member spec outright."""
         with span("fleet.rollup"):
-            w = self.lane_wear(eng)[lanes]
+            w = self.final().elem_wear[lanes, :eng.cfg.n_elements].astype(
+                np.int64)
             if self.elem_mask is None:
                 return w.reshape(-1)
             return w[self.elem_mask[lanes]]
@@ -405,8 +442,8 @@ def _recovery(res: FleetResult, lanes: np.ndarray,
         sel = act & (t == k)
         out[f"tenant{k}_p99_after_failure_s"] = (
             float(np.percentile(lat[sel], 99)) if sel.any() else 0.0)
-    host = np.asarray(res.states.host_pages)[lanes]
-    dummy = np.asarray(res.states.dummy_pages)[lanes]
+    final = res.final()
+    host, dummy = final.host_pages[lanes], final.dummy_pages[lanes]
     for d, (h, pad) in enumerate(zip(host.tolist(), dummy.tolist())):
         out[f"member{d}_dlwa"] = (h + pad) / h if h else 1.0
     return out

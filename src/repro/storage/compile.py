@@ -380,9 +380,10 @@ def lane_state(res: runner.FleetResult, lane: int):
 
 def lane_metrics(eng: ZoneEngine, res: runner.FleetResult,
                  lane: int) -> Dict[str, float]:
-    """``eng.metrics`` of one replay lane (host/dummy/DLWA/erases)."""
+    """``eng.metrics`` of one replay lane (host/dummy/DLWA/erases),
+    from the result's host copy of the final counters."""
     with span("fleet.rollup"):
-        return eng.metrics(lane_state(res, lane))
+        return eng.metrics(res.final().lane(lane))
 
 
 # --------------------------------------------------------------------- #

@@ -452,13 +452,14 @@ def test_counters_are_listed_and_need_a_profiler(eng, mix):
         count("build.rebuild_rows")
         count("any.name", 2)
     assert prof.counters == {"build.rebuild_rows": 6.0, "any.name": 2.0}
-    # a failed config's build lists the rebuild's three counters, and
-    # its dispatch the engine's three
+    # a failed config's build lists the rebuild's three counters, its
+    # dispatch the engine's three and its rollups their one state read
     prof = Profiler()
     Evaluator(eng, n_devices=ND, profiler=prof).evaluate(
         [_config(SUPERBLOCK, "traditional", 0.5)])
     assert set(prof.counters) == set(COUNTERS) | {
-        "engine.groups", "engine.lane_steps", "engine.alloc_steps"}
+        "engine.groups", "engine.lane_steps", "engine.alloc_steps",
+        "rollup.device_reads"}
 
 
 def test_obs_report_shows_the_rebuild_spans_and_counters(eng, mix):
